@@ -10,12 +10,12 @@ activations) per candidate:
   * ``factorized``  — the paper-faithful sequential chain, VJP'd by JAX.
 
 Three config sizes: the bert_base / qwen3_14b smoke FFN shapes the tests
-train at, plus the full-scale bert-base FFN (768 x 3072).  On this CPU
-container the kernel runs in INTERPRET mode — its absolute numbers are
-correctness-path timings, not TPU performance; the reconstruct/factorized
-columns are real XLA-CPU timings.  Results land in ``BENCH_kernel.json``
-next to ``BENCH_engine.json``; re-run on a real TPU (interpret=False) to
-refresh with MXU numbers.
+train at, plus the full-scale bert-base FFN (768 x 3072).  Off the TPU
+the kernel runs in interpret mode (``kernels.tpu.interpret_mode``) — its
+absolute numbers are then correctness-path timings, not TPU performance,
+and the reconstruct/factorized columns are XLA-CPU timings.  Results land
+in ``BENCH_kernel.json`` next to ``BENCH_engine.json``; run on a TPU to
+refresh with compiled-kernel numbers.
 
 Run:  PYTHONPATH=src python -m benchmarks.kernel_vjp
 """
@@ -64,7 +64,8 @@ def run() -> list[str]:
     from repro.core import mpo
     from repro.kernels.mpo_linear import (DEFAULT_BLOCK_M, kernel_eligible,
                                           mpo_linear)
-    from repro.kernels.ops import INTERPRET
+    from repro.kernels.tpu import interpret_mode
+    interpret = interpret_mode()
 
     rows, results = [], []
     for label, shapes in _configs():
@@ -76,18 +77,20 @@ def run() -> list[str]:
             i_dim *= s[1]
         x = jax.random.normal(keys[-1], (TOKENS, i_dim))
 
-        # the kernel is timed even on gate-failing tiles: the row documents
-        # what the eligibility gate saves the planner from
+        # interpreted, the kernel is timed even on gate-failing tiles (the
+        # row documents what the gate saves the planner from); compiled, a
+        # tile the gate refuses does not lower at all
         eligible = kernel_eligible(shapes, DEFAULT_BLOCK_M)
         paths = {
             "factorized": lambda cs, xs: mpo.apply_mpo(list(cs), xs),
             "reconstruct": lambda cs, xs: mpo.matmul_reconstruct(xs, cs),
-            "kernel": lambda cs, xs: mpo_linear(
-                cs, xs, block_m=DEFAULT_BLOCK_M, interpret=INTERPRET),
         }
+        if interpret or eligible:
+            paths["kernel"] = lambda cs, xs: mpo_linear(
+                cs, xs, block_m=DEFAULT_BLOCK_M, interpret=interpret)
 
         entry = {"config": label, "shapes": [list(s) for s in shapes],
-                 "tokens": TOKENS, "interpret": INTERPRET,
+                 "tokens": TOKENS, "interpret": interpret,
                  "kernel_eligible": eligible, "fwd_bwd_s": {}}
         for name, fn in paths.items():
             step = jax.jit(jax.grad(
@@ -98,9 +101,11 @@ def run() -> list[str]:
             rows.append(f"kernel_vjp,{label},{name},fwd_bwd_s={t:.6f}")
         results.append(entry)
 
-    payload = {"tokens": TOKENS, "reps": REPS, "interpret": INTERPRET,
-               "note": ("fwd+bwd step time; kernel timed in interpret mode "
-                        "on CPU containers — correctness path, not TPU perf"),
+    payload = {"tokens": TOKENS, "reps": REPS, "interpret": interpret,
+               "device": jax.devices()[0].device_kind,
+               "note": ("fwd+bwd step time" + (
+                   "; kernel interpreted off the TPU — correctness path, "
+                   "not TPU perf" if interpret else "")),
                "results": results}
     with open(_JSON_PATH, "w") as f:
         json.dump(payload, f, indent=2)

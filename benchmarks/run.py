@@ -1,16 +1,18 @@
 """Benchmark harness — one entry per paper table/figure.
 
 ``PYTHONPATH=src python -m benchmarks.run [table1 table2 ...]``
-Prints ``name,metric,...`` CSV rows per the assignment contract.
+Prints ``name,metric,...`` CSV rows per the assignment contract; exits
+non-zero when any requested suite raised.
 """
 
 from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 
-def main() -> None:
+def main() -> int:
     from benchmarks import (decode_attention, engine_modes, fig2_lowrank,
                             kernel_vjp, roofline, router_fleet, serve_pool,
                             table1_variation, table2_complexity,
@@ -32,16 +34,21 @@ def main() -> None:
         "router": router_fleet.run,
     }
     want = sys.argv[1:] or list(suites)
+    failed = []
     for name in want:
         t0 = time.time()
         try:
             rows = suites[name]()
         except Exception as e:  # pragma: no cover
+            # keep running the other suites, but the run as a whole fails
+            traceback.print_exc()
+            failed.append(name)
             rows = [f"{name},ERROR,{type(e).__name__}: {e}"]
         for r in rows:
             print(r)
         print(f"# {name} done in {time.time() - t0:.1f}s", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

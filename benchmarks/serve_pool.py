@@ -93,7 +93,9 @@ def run(device_counts=(1, 4, 8)) -> list[str]:
     for n in device_counts:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env.pop("JAX_PLATFORMS", None)
+        # children are CPU-only by design: forced host devices stand in for
+        # a mesh, and a chip belongs to the one process that opened it
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = os.path.join(_ROOT, "src")
         r = subprocess.run(
             [sys.executable, "-m", "benchmarks.serve_pool", "--worker",

@@ -15,6 +15,10 @@ bytes against a per-core budget, and enforces the centralized tile rules:
                             alignment rules (``BLOCK_M_ALIGN``, lane=128)
                             — a tripwire against editing one constant
                             without the other.
+``kernel/block-shape``      Mosaic's block-shape rule: the last two dims of
+                            every ``BlockSpec`` block divisible by (8, 128)
+                            or equal to the array's — interpret mode never
+                            checks it, the TPU compiler refuses the kernel.
 ``kernel/page-bounds``      ``decode_attention``'s page-table index maps,
                             evaluated at the corner cases (empty slot,
                             full slot, unmapped ``-1`` pages, last logical
@@ -31,6 +35,7 @@ from repro.analysis.findings import Finding
 from repro.kernels import autotune
 from repro.kernels import decode_attention as DA
 from repro.kernels import mpo_linear as MK
+from repro.kernels.tpu import block_shape_ok
 
 MPO_FILE = "src/repro/kernels/mpo_linear.py"
 DA_FILE = "src/repro/kernels/decode_attention.py"
@@ -54,10 +59,24 @@ def _fmt_mib(b: int) -> str:
     return f"{b / (1024 * 1024):.2f} MiB"
 
 
+def lint_block_shapes(rows, *, file: str, location: str,
+                      config: str = "") -> list:
+    """``kernel/block-shape`` errors for ``(name, block, array)`` rows that
+    break Mosaic's (8, 128)-or-full rule."""
+    return [Finding(
+        check="kernel/block-shape", severity="error", file=file,
+        location=f"{location}:{name}",
+        message=f"block {tuple(block)} over array {tuple(array)}: the last "
+                f"two block dims must be divisible by (8, 128) or equal the "
+                f"array's — the TPU compiler refuses this kernel",
+        config=config)
+        for name, block, array in rows if not block_shape_ok(block, array)]
+
+
 def lint_mpo_call(shapes, *, config: str = "", location: str = "",
                   itemsize: int = 4,
                   budget: int = DEFAULT_VMEM_BUDGET,
-                  eligible_fn=None) -> list:
+                  eligible_fn=None, blocks_fn=None) -> list:
     """Budget findings for one fused-MPO-linear call site (one core shape
     set), in all three program variants the custom_vjp can run: forward,
     dx (forward kernel over i/j-swapped cores), and the cores-backward.
@@ -68,8 +87,10 @@ def lint_mpo_call(shapes, *, config: str = "", location: str = "",
     the gate and the residency model have diverged (someone relaxed one
     without the other).  ``eligible_fn`` is injectable so the regression
     test can seed the pre-fix gate (alignment only) and watch the
-    over-budget tile get reported."""
+    over-budget tile get reported; ``blocks_fn`` (``MK.block_shapes``'s
+    signature) likewise seeds a block geometry the compiler refuses."""
     eligible_fn = eligible_fn or MK.kernel_eligible
+    blocks_fn = blocks_fn or MK.block_shapes
     shapes = tuple(tuple(s) for s in shapes)
     loc = location or "x".join(str(d) for s in shapes for d in s)
     findings = []
@@ -85,6 +106,9 @@ def lint_mpo_call(shapes, *, config: str = "", location: str = "",
             if not eligible_fn(shapes, bm, train=train):
                 continue
             any_admitted = True
+            findings += lint_block_shapes(
+                blocks_fn(shp, bm, bm, backward=backward), file=MPO_FILE,
+                location=f"{loc}:{label}@block_m={bm}", config=config)
             used = residency_bytes(MK.vmem_buffers(
                 shp, bm, bm, itemsize, backward=backward))
             if used > budget:
@@ -97,17 +121,14 @@ def lint_mpo_call(shapes, *, config: str = "", location: str = "",
                             f"exceeds the {_fmt_mib(budget)} per-core "
                             f"budget — compiling it would abort on "
                             f"hardware", config=config))
-    ins = [s[1] for s in shapes]
-    outs = [s[2] for s in shapes]
-    aligned = (math.prod(ins[1:]) % MK.BLOCK_M_ALIGN == 0
-               and math.prod(outs[1:]) % 128 == 0)
-    if aligned and not any_admitted:
+    if MK.layout_ok(shapes) and not any_admitted:
         findings.append(Finding(
             check="kernel/vmem-budget", severity="info", file=MPO_FILE,
             location=loc,
-            message="MXU-aligned shape set, but no candidate tile fits the "
-                    "VMEM budget — the fused kernel is disabled for this "
-                    "matrix (planner falls back to factorized/reconstruct)",
+            message="the compiler accepts this layout, but no candidate "
+                    "tile fits the VMEM budget — the fused kernel is "
+                    "disabled for this matrix (planner falls back to "
+                    "factorized/reconstruct)",
             config=config))
     return findings
 
@@ -115,15 +136,21 @@ def lint_mpo_call(shapes, *, config: str = "", location: str = "",
 def lint_decode_attention_call(num_kv_heads: int, group: int, head_dim: int,
                                page_size: int, max_pages: int, *,
                                config: str = "", itemsize: int = 2,
-                               budget: int = DEFAULT_VMEM_BUDGET) -> list:
-    """Budget + alignment + index-map-bounds findings for one flash
-    decode-attention geometry."""
+                               budget: int = DEFAULT_VMEM_BUDGET,
+                               blocks_fn=None) -> list:
+    """Budget + block-shape + alignment + index-map-bounds findings for one
+    flash decode-attention geometry (``blocks_fn`` seeds a block geometry,
+    as in ``lint_mpo_call``)."""
     loc = (f"kv={num_kv_heads},g={group},dh={head_dim},"
            f"ps={page_size},mp={max_pages}")
-    findings = []
+    blocks_fn = blocks_fn or DA.block_shapes
+    # two slots sharing the pool: a block must fit any slot/page count
+    findings = lint_block_shapes(
+        blocks_fn(2, num_kv_heads, group, head_dim, page_size, max_pages,
+                  2 * max_pages), file=DA_FILE, location=loc, config=config)
 
-    used = residency_bytes(DA.vmem_buffers(group, head_dim, page_size,
-                                           itemsize))
+    used = residency_bytes(DA.vmem_buffers(num_kv_heads, group, head_dim,
+                                           page_size, itemsize))
     if used > budget:
         findings.append(Finding(
             check="kernel/vmem-budget", severity="error", file=DA_FILE,
@@ -159,7 +186,7 @@ def lint_decode_attention_call(num_kv_heads: int, group: int, head_dim: int,
         for ln in len_cases:
             lens = np.array([ln], np.int32)
             for p in (0, max(max_pages - 1, 0)):
-                idx = DA._kv_index_map(0, 0, p, table, lens,
+                idx = DA._kv_index_map(0, p, table, lens,
                                        page_size=page_size,
                                        max_pages=max_pages)
                 phys = int(idx[0])
@@ -172,7 +199,7 @@ def lint_decode_attention_call(num_kv_heads: int, group: int, head_dim: int,
                         message=f"physical page index {phys} is outside the "
                                 f"pool [0, {pool}) — out-of-bounds DMA",
                         config=config))
-                b_idx = DA._bias_index_map(0, 0, p, table, lens,
+                b_idx = DA._bias_index_map(0, p, table, lens,
                                            page_size=page_size)
                 lp = int(b_idx[1])
                 if not 0 <= lp < max_pages:

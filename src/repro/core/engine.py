@@ -39,7 +39,7 @@ engine centralizes the decision:
   hardware (or ``REPRO_AUTOTUNE_MEASURE=1``), the train/prefill decision and
   the kernel tile height ``block_m`` come from ``kernels.autotune``: a small
   candidate grid is TIMED once per (shapes, tokens, phase, dtype) key and
-  the verdict persists to ``~/.cache/repro/autotune.json``
+  the verdict persists to ``<checkout>/.cache/repro/autotune.json``
   (``REPRO_AUTOTUNE_CACHE``), so later processes plan with zero timing runs.
   Interpret mode keeps the analytic FLOPs heuristic.
 * **Serving weight cache** — ``MPOEngine.cache_weights(params)`` walks a
@@ -81,6 +81,7 @@ from repro.kernels import autotune
 # rules lives with the kernel itself (kernels.mpo_linear) — re-exported here
 # because planning call sites historically import them from the engine
 from repro.kernels.mpo_linear import DEFAULT_BLOCK_M, kernel_eligible
+from repro.kernels.tpu import interpret_mode
 
 PHASES = ("train", "prefill", "decode")
 MODES = ("factorized", "reconstruct", "kernel", "cached")
@@ -182,15 +183,13 @@ def _decide(cfg, shapes: tuple, tokens: int, phase: str, interpret: bool,
             f"factorized {fact_tok} <= dense {dense_tok} "
             "FLOPs/token; caching W would also cost I*J HBM")
     if autotune.should_measure(interpret):
-        try:
-            res = autotune.get_tuner().get(shapes, tokens, phase, dtype,
-                                           interpret)
-        except Exception:  # tuning must never take planning down
-            res = None
-        if res is not None:
-            return res.mode, res.block_m, True, (
-                f"autotuned ({res.source}): {res.mode}@{res.block_m} "
-                f"fastest of {len(res.timings)} candidates")
+        # a candidate that fails to compile or run is a defect of the main
+        # path: it fails planning instead of degrading to the heuristic
+        res = autotune.get_tuner().get(shapes, tokens, phase, dtype,
+                                       interpret)
+        return res.mode, res.block_m, True, (
+            f"autotuned ({res.source}): {res.mode}@{res.block_m} "
+            f"fastest of {len(res.timings)} candidates")
     cost_fact = tokens * fact_tok
     cost_recon = rebuild + tokens * dense_tok
     if cost_fact < cost_recon:
@@ -200,15 +199,18 @@ def _decide(cfg, shapes: tuple, tokens: int, phase: str, interpret: bool,
     # differentiable kernel: a candidate for fwd+bwd (train) and forward-only
     # (prefill) alike — the backward accumulates core-space gradients
     # on-chip, so no dense dW traffic disqualifies it.  train's dL/dx pass
-    # runs the kernel over i/j-SWAPPED cores, so both tile orientations must
-    # clear the alignment floor.
-    eligible = kernel_eligible(shapes, DEFAULT_BLOCK_M,
-                               train=phase == "train")
-    if not interpret and eligible:
+    # runs the kernel over i/j-SWAPPED cores, so both orientations must
+    # compile and fit.  The tile is the largest candidate up to the default
+    # that the eligibility gate admits.
+    tiles = [bm for bm in sorted(autotune.CANDIDATE_BLOCK_MS, reverse=True)
+             if bm <= DEFAULT_BLOCK_M
+             and kernel_eligible(shapes, bm, train=phase == "train")]
+    if not interpret and tiles:
         what = "fwd+bwd" if phase == "train" else "forward-only"
-        return "kernel", DEFAULT_BLOCK_M, False, (
-            f"dense-favored {what} phase on TPU with MXU-aligned tiles: "
-            "fuse rebuild on-chip (analytic gate; no measurement available)")
+        return "kernel", tiles[0], False, (
+            f"dense-favored {what} phase on TPU with a tile the compiler "
+            "accepts: fuse rebuild on-chip (analytic gate; no measurement "
+            "available)")
     return "reconstruct", DEFAULT_BLOCK_M, False, (
         f"rebuild+dense {cost_recon} <= chain {cost_fact} "
         f"FLOPs at {tokens} tokens")
@@ -257,13 +259,19 @@ def clear_plan_cache() -> None:
 # --------------------------------------------------------------------------
 
 
-def _reconstruct_stacked(cores: Sequence[jax.Array]) -> jax.Array:
-    """``mpo.reconstruct`` vmapped over any leading stacked dims (scanned
-    layers, MoE experts) — cores are 4-D per matrix plus k batch dims."""
-    fn = lambda *cs: mpo.reconstruct(list(cs))
+@jax.jit
+def _reconstruct_stacked(cores: tuple) -> jax.Array:
+    """``mpo.reconstruct`` mapped over any leading stacked dims (scanned
+    layers, MoE experts) — cores are 4-D per matrix plus k batch dims.
+    One compiled program per matrix, one stacked matrix at a time inside it
+    (``lax.map``): run op by op, every chain intermediate (GBs for a
+    vocabulary head) would stay resident until Python dropped it, and in
+    one program for the whole snapshot the transients of several matrices
+    would coexist."""
+    fn = lambda cs: mpo.reconstruct(list(cs))
     for _ in range(cores[0].ndim - 4):
-        fn = jax.vmap(fn)
-    return fn(*cores)
+        fn = functools.partial(jax.lax.map, fn)
+    return fn(cores)
 
 
 class MPOEngine:
@@ -285,15 +293,14 @@ class MPOEngine:
 
     def __init__(self, cfg, *, interpret: bool | None = None):
         self.cfg = cfg
-        # None -> follow the kernels.ops container default at call time
+        # None -> follow the backend at call time (kernels.tpu.interpret_mode)
         self._interpret = interpret
 
     @property
     def interpret(self) -> bool:
         if self._interpret is not None:
             return self._interpret
-        from repro.kernels import ops  # lazy: avoid import cycle
-        return ops.INTERPRET
+        return interpret_mode()
 
     # ---- planning ----
 
@@ -398,8 +405,11 @@ class MPOEngine:
                     plan = self.plan(shapes, 1, "decode")
                     if plan.mode != "cached":
                         return node, ax
-                    w = _reconstruct_stacked(cores)
+                    w = _reconstruct_stacked(tuple(cores))
                     if dtype is not None:
+                        # a program of its own: fused into the rebuild, the
+                        # cast made XLA plan a v5e head rebuild at 10.5 GB
+                        # of temporaries instead of 3.5 GB
                         w = w.astype(dtype)
                     new_ax = ax
                     if ax is not None:

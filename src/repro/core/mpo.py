@@ -158,11 +158,6 @@ def _interleave_perm(n: int) -> list[int]:
     return perm
 
 
-def _deinterleave_perm(n: int) -> list[int]:
-    """(i1, j1, i2, j2, ...) -> (i1..in, j1..jn)."""
-    return [2 * k for k in range(n)] + [2 * k + 1 for k in range(n)]
-
-
 def decompose(matrix: jax.Array, spec: MPOSpec):
     """Algorithm 1: sequential-SVD MPO decomposition with bond truncation.
 
@@ -195,36 +190,26 @@ def decompose(matrix: jax.Array, spec: MPOSpec):
 def reconstruct(cores: Sequence[jax.Array]) -> jax.Array:
     """Contract cores back to the (approximate) matrix ``W[I, J]``.
 
-    Core 0's i/j legs are kept as SEPARATE leading axes throughout the chain
-    (they may be TP-sharded): merging a sharded inner leg into a flattened
-    dim produces a strided tiling GSPMD cannot express, forcing per-layer
-    all-reduces of W-sized intermediates (observed 13 GiB/step on the decode
-    cells; §Perf it.11).  With leading legs, every chain matmul is local and
-    the final reshape keeps contiguous row/col tiles.
+    The chain runs from the LAST core to the first, keeping the partial
+    product as ``T[d, (i_k..i_n), (j_k..j_n)]``: each step is one matmul
+    and one transpose whose minor dim is the merged trailing-j block, which
+    grows with every step.  An interleaved ``(i1, j1, ..., in, jn)`` staging
+    would instead hold W-sized tensors whose minor dim is the single last
+    factor ``jn``; a TPU pads that dim to 128 lanes (a 6-wide factor costs
+    21x the bytes, more than a chip holds for a vocabulary head).
+
+    Core 0's i/j legs are contracted last and stay the outermost digits of
+    W's rows and columns, so a TP-sharded core 0 (``layers._core_axes``)
+    yields contiguous row/column tiles with every matmul local.
     """
-    n = len(cores)
-    ins = [c.shape[1] for c in cores]
-    outs = [c.shape[2] for c in cores]
-    if n == 1:
-        return cores[0][0, :, :, 0]
-    acc = cores[0][0]  # (i1, j1, d1) — legs kept separate
-    i1, j1 = ins[0], outs[0]
-    mid = 1
-    for c in cores[1:]:
-        d0, ik, jk, d1 = c.shape
-        acc = jnp.einsum("abmd,dx->abmx",
-                         acc.reshape(i1, j1, mid, d0),
-                         c.reshape(d0, ik * jk * d1))
-        mid *= ik * jk
-        acc = acc.reshape(i1, j1, mid, d1)
-    # acc: (i1, j1, (i2 j2 ... in jn), 1) -> (I, J)
-    rest = [x for k in range(1, n) for x in (ins[k], outs[k])]
-    t = acc.reshape([i1, j1] + rest)
-    # interleaved (i2,j2,...) -> (i2..in, j2..jn)
-    perm = ([0] + [2 + 2 * k for k in range(n - 1)]
-            + [1] + [3 + 2 * k for k in range(n - 1)])
-    t = t.transpose(perm)
-    return t.reshape(math.prod(ins), math.prod(outs))
+    t = cores[-1][..., 0]                          # (d, i_n, j_n)
+    for c in reversed(cores[:-1]):
+        d, i, j, e = c.shape
+        _, ir, jr = t.shape
+        b = c.reshape(d * i * j, e) @ t.reshape(e, ir * jr)
+        t = (b.reshape(d, i, j, ir, jr).transpose(0, 1, 3, 2, 4)
+             .reshape(d, i * ir, j * jr))
+    return t[0]
 
 
 # --------------------------------------------------------------------------
@@ -325,23 +310,6 @@ def _mm_recon_fwd(x, cores):
     return x @ reconstruct(list(cores)), (x, cores)
 
 
-def reconstruct_merged(cores: Sequence[jax.Array]) -> jax.Array:
-    """Legacy chain staging (rows merged as it goes).  Equal values to
-    ``reconstruct``; its VJP shards better for the dW->dcores projection
-    (the legs-leading staging regresses the train backward 2x; §Perf it.12)."""
-    n = len(cores)
-    ins = [c.shape[1] for c in cores]
-    outs = [c.shape[2] for c in cores]
-    acc = cores[0].reshape(-1, cores[0].shape[-1])  # (i1*j1, d1)
-    for c in cores[1:]:
-        d0 = c.shape[0]
-        acc = acc @ c.reshape(d0, -1)
-        acc = acc.reshape(-1, c.shape[-1])
-    t = acc.reshape([x for k in range(n) for x in (ins[k], outs[k])])
-    t = t.transpose(_deinterleave_perm(n))
-    return t.reshape(math.prod(ins), math.prod(outs))
-
-
 def _project_dw(cores, x, dy):
     """dcores from local tokens: dW = x^T dy projected into core-space.
 
@@ -351,7 +319,7 @@ def _project_dw(cores, x, dy):
     all-gathers in the remat backward (§Perf it.15).
     """
     dw = jnp.einsum("...i,...j->ij", x, dy)
-    _, vjp = jax.vjp(lambda cs: reconstruct_merged(list(cs)), cores)
+    _, vjp = jax.vjp(lambda cs: reconstruct(list(cs)), cores)
     (dcores,) = vjp(dw.astype(cores[0].dtype))
     return dcores
 

@@ -14,8 +14,8 @@ of this PR), forward-only phases as plain forwards.
 Results persist to an on-disk JSON cache so subsequent processes (CI, the
 next serving session) pay ZERO tuning cost:
 
-* location: ``~/.cache/repro/autotune.json``, overridable via the
-  ``REPRO_AUTOTUNE_CACHE`` env var;
+* location: ``<checkout>/.cache/repro/autotune.json`` (``repro.runtime``),
+  overridable via the ``REPRO_AUTOTUNE_CACHE`` env var;
 * corrupted / stale / wrong-version files are IGNORED (re-tuned and
   rewritten), never crashed on;
 * delete the file (or point ``REPRO_AUTOTUNE_CACHE`` elsewhere) to force a
@@ -66,8 +66,8 @@ def cache_path() -> str:
     env = os.environ.get(ENV_CACHE)
     if env:
         return env
-    return os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                        "autotune.json")
+    from repro.runtime import CACHE_ROOT  # lazy: kernels import standalone
+    return str(CACHE_ROOT / "repro" / "autotune.json")
 
 
 def should_measure(interpret: bool) -> bool:
@@ -117,6 +117,19 @@ def _block_m_candidates(tokens: int) -> list[int]:
     return out
 
 
+def chain_in_race(shapes, tokens: int) -> bool:
+    """Is the factorized chain a candidate?  Only where its FLOPs at this
+    token count do not exceed rebuild + dense matmul.  Timed alone, XLA may
+    reassociate the chain of small matmuls into a rebuild of W (a v5e race
+    at qwen3-14b widths timed both paths equal with the chain at 3-60x the
+    dense FLOPs), while inside a layer the chain runs as written and its
+    intermediates, many times W's size, outgrow HBM."""
+    from repro.core import engine  # lazy: engine imports this module
+    return (tokens * engine.flops_factorized_per_token(shapes)
+            <= engine.flops_reconstruct(shapes)
+            + tokens * engine.flops_dense_per_token(shapes))
+
+
 def _candidates(shapes, tokens, phase, dtype, interpret):
     """[(label, jitted zero-arg fn)] — real implementations over synthetic
     operands of the tuned shapes.  train times fwd+bwd, others fwd-only."""
@@ -129,8 +142,9 @@ def _candidates(shapes, tokens, phase, dtype, interpret):
     i_dim = math.prod(s[1] for s in shapes)
     x = jax.random.normal(keys[-1], (int(tokens), i_dim)).astype(jdt)
 
-    fwd = {"factorized": lambda cs, xs: mpo.apply_mpo(list(cs), xs),
-           "reconstruct": lambda cs, xs: mpo.matmul_reconstruct(xs, cs)}
+    fwd = {"reconstruct": lambda cs, xs: mpo.matmul_reconstruct(xs, cs)}
+    if chain_in_race(shapes, tokens):
+        fwd["factorized"] = lambda cs, xs: mpo.apply_mpo(list(cs), xs)
     for bm in _block_m_candidates(tokens):
         if kernel_eligible(shapes, bm, train=phase == "train"):
             fwd[f"kernel@{bm}"] = (
@@ -250,6 +264,8 @@ class Autotuner:
 
     def measure(self, shapes, tokens, phase, dtype, interpret,
                 candidates_fn=None) -> TuneResult:
+        """Time every candidate; a candidate that fails is a defect of the
+        main path and fails the race (no partial verdict)."""
         candidates_fn = candidates_fn or _candidates
         timings = [(label, self._time(fn)) for label, fn in
                    candidates_fn(shapes, tokens, phase, dtype, interpret)]

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tpu import interpret_mode
 from repro.resilience import faults
 
 ENV_IMPL = "REPRO_DECODE_ATTN"      # "flash" | "xla" force-override
@@ -71,10 +72,13 @@ def note_fallback(exc: BaseException) -> None:
 def _flash_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, page_size: int, scale: float,
                   softcap: float | None):
-    """Grid (B, KV, num_pages); page index innermost so the f32 scratch
-    (acc / running max / normalizer) persists across a slot's pages."""
+    """Grid (B, num_pages); page index innermost so the f32 scratch
+    (acc / running max / normalizer, one row block per KV head) persists
+    across a slot's pages.  Each program attends ALL KV heads of one page:
+    the page block spans the full head dim, as Mosaic's block-shape rule
+    requires of the second-to-last dim."""
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
     npages = (lens_ref[b] + page_size - 1) // page_size
 
     @pl.when(p == 0)
@@ -86,67 +90,81 @@ def _flash_kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
 
     @pl.when(p < npages)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, Dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (ps, Dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        q = q_ref[0].astype(jnp.float32)                       # (KV, G, Dh)
+        k = k_ref[0].astype(jnp.float32).transpose(1, 0, 2)    # (KV, ps, Dh)
+        v = v_ref[0].astype(jnp.float32).transpose(1, 0, 2)
+        s = jnp.einsum("hgd,hpd->hgp", q, k,
+                       preferred_element_type=jnp.float32) * scale
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
-        s = s + bias_ref[0][None, :]               # additive mask, (1, ps)
+        s = s + bias_ref[0, 0][None]               # additive mask, (1, 1, ps)
         m_prev = m_ref[...]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
         w = jnp.exp(s - m_cur)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(w, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-            w, v, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "hgp,hpd->hgd", w, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_cur
 
     @pl.when(p == jnp.maximum(npages, 1) - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], _TINY)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def vmem_buffers(group: int, head_dim: int, page_size: int,
-                 itemsize: int) -> list:
+def block_shapes(batch: int, num_kv_heads: int, group: int, head_dim: int,
+                 page_size: int, max_pages: int, pool_pages: int) -> list:
+    """``(name, block, array)`` for every ``BlockSpec`` of
+    ``flash_decode_attention`` — what ``repro.analysis.kernel_budget``
+    checks against Mosaic's block-shape rule."""
+    kv, g, dh, ps = num_kv_heads, group, head_dim, page_size
+    page = ((1, ps, kv, dh), (pool_pages, ps, kv, dh))
+    heads = ((1, kv, g, dh), (batch, kv, g, dh))
+    return [("q", *heads), ("k_page", *page), ("v_page", *page),
+            ("bias", (1, 1, 1, ps), (batch, max_pages, 1, ps)),
+            ("out", *heads)]
+
+
+def vmem_buffers(num_kv_heads: int, group: int, head_dim: int,
+                 page_size: int, itemsize: int) -> list:
     """One program's VMEM-resident buffers: ``(name, shape, bytes_per_elem,
     pipelined)`` rows mirroring the ``BlockSpec``s + ``scratch_shapes`` of
     ``flash_decode_attention`` below — kept in this file so the residency
     model and the specs change together.  Consumed by
     ``repro.analysis.kernel_budget`` (pipelined rows cost 2x: Pallas
     double-buffers streamed blocks; scratch is resident once)."""
-    g, dh, ps = group, head_dim, page_size
+    kv, g, dh, ps = num_kv_heads, group, head_dim, page_size
     return [
-        ("q", (1, 1, g, dh), itemsize, True),
-        ("k_page", (1, ps, 1, dh), itemsize, True),
-        ("v_page", (1, ps, 1, dh), itemsize, True),
-        ("bias", (1, ps), 4, True),          # additive mask arrives f32
-        ("out", (1, 1, g, dh), itemsize, True),
-        ("acc_scratch", (g, dh), 4, False),
-        ("m_scratch", (g, 1), 4, False),
-        ("l_scratch", (g, 1), 4, False),
+        ("q", (1, kv, g, dh), itemsize, True),
+        ("k_page", (1, ps, kv, dh), itemsize, True),
+        ("v_page", (1, ps, kv, dh), itemsize, True),
+        ("bias", (1, 1, 1, ps), 4, True),      # additive mask arrives f32
+        ("out", (1, kv, g, dh), itemsize, True),
+        ("acc_scratch", (kv, g, dh), 4, False),
+        ("m_scratch", (kv, g, 1), 4, False),
+        ("l_scratch", (kv, g, 1), 4, False),
     ]
 
 
-def _kv_index_map(b, h, p, table, lens, *, page_size, max_pages):
+def _kv_index_map(b, p, table, lens, *, page_size, max_pages):
     """Physical page for (slot b, logical page p), clamped to the slot's
     last valid page — consecutive identical block indices make Mosaic skip
     the re-fetch, which is what bounds a slot's bandwidth by its length."""
     npages = (lens[b] + page_size - 1) // page_size
     lp = jnp.minimum(p, jnp.maximum(npages - 1, 0))
     phys = jnp.maximum(table[b * max_pages + lp], 0)
-    return phys, 0, h, 0
+    return phys, 0, 0, 0
 
 
-def _bias_index_map(b, h, p, table, lens, *, page_size):
+def _bias_index_map(b, p, table, lens, *, page_size):
     npages = (lens[b] + page_size - 1) // page_size
-    return b, jnp.minimum(p, jnp.maximum(npages - 1, 0))
+    return b, jnp.minimum(p, jnp.maximum(npages - 1, 0)), 0, 0
 
 
 def flash_decode_attention(q, k_pages, v_pages, page_table, lengths, bias,
                            *, softcap: float | None = None,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """Single-token flash decoding over paged KV.
 
     q:          (B, KV, G, Dh)   — grouped query heads (H = KV * G)
@@ -156,19 +174,22 @@ def flash_decode_attention(q, k_pages, v_pages, page_table, lengths, bias,
     bias:       (B, MP * ps) f32 — additive mask (0 keep / MASK_VALUE drop)
 
     Returns (B, KV, G, Dh) in q's dtype.  Softmax statistics are f32.
+    ``interpret`` defaults to ``kernels.tpu.interpret_mode()``.
     """
     faults.check_flash()   # chaos: simulate a kernel failure at trace time
+    if interpret is None:
+        interpret = interpret_mode()
     return _flash_jit(q, k_pages, v_pages, page_table, lengths, bias,
                       softcap=softcap, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
 def _flash_jit(q, k_pages, v_pages, page_table, lengths, bias,
-               *, softcap: float | None = None, interpret: bool = True):
+               *, softcap: float | None = None, interpret: bool):
     b, kv, g, dh = q.shape
     _, page_size, _, _ = k_pages.shape
     max_pages = page_table.shape[1]
-    grid = (b, kv, max_pages)
+    grid = (b, max_pages)
     kv_map = functools.partial(_kv_index_map, page_size=page_size,
                                max_pages=max_pages)
     bias_map = functools.partial(_bias_index_map, page_size=page_size)
@@ -180,20 +201,21 @@ def _flash_jit(q, k_pages, v_pages, page_table, lengths, bias,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, dh), lambda b, h, p, t, L: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, dh), kv_map),
-                pl.BlockSpec((1, page_size, 1, dh), kv_map),
-                pl.BlockSpec((1, page_size), bias_map),
+                pl.BlockSpec((1, kv, g, dh), lambda b, p, t, L: (b, 0, 0, 0)),
+                pl.BlockSpec((1, page_size, kv, dh), kv_map),
+                pl.BlockSpec((1, page_size, kv, dh), kv_map),
+                pl.BlockSpec((1, 1, 1, page_size), bias_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, dh),
-                                   lambda b, h, p, t, L: (b, h, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((g, dh), jnp.float32),
-                            pltpu.VMEM((g, 1), jnp.float32),
-                            pltpu.VMEM((g, 1), jnp.float32)],
+            out_specs=pl.BlockSpec((1, kv, g, dh),
+                                   lambda b, p, t, L: (b, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((kv, g, dh), jnp.float32),
+                            pltpu.VMEM((kv, g, 1), jnp.float32),
+                            pltpu.VMEM((kv, g, 1), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dh), q.dtype),
         interpret=interpret,
-    )(page_table.reshape(-1), lengths, q, k_pages, v_pages, bias)
+    )(page_table.reshape(-1), lengths, q, k_pages, v_pages,
+      bias.reshape(b, max_pages, 1, page_size))
 
 
 # --------------------------------------------------------------------------
@@ -259,7 +281,7 @@ def _race_candidates(shapes, tokens, phase, dtype, interpret):
 
 def choose_impl(num_kv_heads: int, group: int, head_dim: int,
                 page_size: int, max_pages: int, dtype: str,
-                interpret: bool = True) -> str:
+                interpret: bool | None = None) -> str:
     """"flash" or "xla", decided at trace time from static info only.
 
     Priority: ``REPRO_DECODE_ATTN`` env force > measured autotuner race
@@ -270,16 +292,13 @@ def choose_impl(num_kv_heads: int, group: int, head_dim: int,
     forced = os.environ.get(ENV_IMPL)
     if forced in ("flash", "xla"):
         return forced
+    if interpret is None:
+        interpret = interpret_mode()
     from repro.kernels import autotune  # lazy: no import cycle at module load
     if autotune.should_measure(interpret):
         shapes = ((num_kv_heads, group, head_dim), (page_size, max_pages))
         bucket = _context_bucket(max_pages * page_size)
-        try:
-            res = autotune.get_tuner().get(
-                shapes, bucket, "decode_attn", dtype, interpret,
-                candidates_fn=_race_candidates)
-        except Exception:   # tuning must never take the decode step down
-            res = None
-        if res is not None and res.mode in ("flash", "xla"):
-            return res.mode
+        return autotune.get_tuner().get(
+            shapes, bucket, "decode_attn", dtype, interpret,
+            candidates_fn=_race_candidates).mode
     return "xla" if interpret else "flash"

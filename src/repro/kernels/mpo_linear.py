@@ -9,6 +9,21 @@ W never exists in HBM — per-step HBM traffic is activations + *compressed*
 cores only, which is the TPU-native realization of the paper's compression
 claim (DESIGN §3.2).
 
+Tile layout.  Mosaic lowers only reshapes that keep the lane (last) dim a
+multiple of 128, so the W tile is rebuilt in two parts:
+
+* the LEFT part ``L[(a_l), (b_l, e)]`` — core 0's (i1, j1) bond fiber
+  chained through cores ``1 .. k-1`` in-kernel (their bonds are the wide,
+  lane-aligned ones);
+* the RIGHT part ``R[e, a_r, b_r]`` — the trailing cores ``k ..`` (small
+  legs, narrow bonds) contracted once per call in XLA; it is tiny (for
+  qwen3-14b ``wq``: 128 x 16 x 16).
+
+For each trailing output index ``s`` the program forms the column block
+``W_s[(a_l, a_r), b_l] = sum_e L[a_l, b_l, e] R[e, a_r, s]`` and
+accumulates ``x_tile @ W_s`` into the output block ``(j1, b_r, M, b_l)``,
+which the wrapper transposes back to ``(M, J)``.
+
 Forward grid: ``(M/bm, j1, i1)`` — i1 innermost = sequential reduction over
 the output tile (standard Pallas accumulation pattern).
 
@@ -18,17 +33,16 @@ Backward (``jax.custom_vjp``) stays fused and core-space:
   cores (swap every core's i/j legs): the cotangent is contracted against
   tile-reconstructed W^T tiles, never a dense W^T.
 * ``dL/dcores`` runs ``_bwd_cores_kernel`` on grid ``(i1, j1, M/bm)``: each
-  program forms one ``(I/i1, J/j1)`` tile of ``dW = x^T dy`` in VMEM and
-  immediately pulls it back through the tile-reconstruction chain
-  (``jax.vjp`` of ``_tile_w`` — a handful of core-sized matmuls), so the
-  gradient is *accumulated directly in core space*.  The dense dW — whose
-  per-layer all-reduce is exactly what lightweight fine-tuning exists to
-  avoid — never materializes in HBM (or anywhere: only one tile of it ever
-  exists, on-chip).
+  program forms ``dW_s = x^T dy_s`` column blocks in VMEM and immediately
+  pulls them back into ``dR`` and through the left chain (``jax.vjp`` of
+  ``_build_left``), so the gradient is *accumulated directly in core
+  space*; ``dR`` goes back to the trailing cores in XLA.  The dense dW —
+  whose per-layer all-reduce is exactly what lightweight fine-tuning exists
+  to avoid — never materializes.
 
 This is what makes ``kernel`` a legal ``train``-phase mode: the engine's
-planner (``core.engine`` + ``kernels.autotune``) may now pick it for
-fwd+bwd workloads, not just forward-only prefill.
+planner (``core.engine`` + ``kernels.autotune``) may pick it for fwd+bwd
+workloads, not just forward-only prefill.
 """
 
 from __future__ import annotations
@@ -40,6 +54,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.tpu import LANES, SUBLANES, block_shape_ok
 
 # Single source of truth for the kernel tile height (imported by
 # ``core.engine`` and ``kernels.autotune`` — do not re-declare):
@@ -61,40 +77,75 @@ def validate_block_m(block_m: int) -> None:
                          f"{BLOCK_M_ALIGN}, got {block_m}")
 
 
+def _split(n: int) -> int:
+    """Cores ``[1, k)`` are chained in-kernel, ``[k, n)`` form R."""
+    return max(1, n - 2)
+
+
+def _geometry(shapes: Sequence[tuple]) -> tuple:
+    """``(k, a_l, b_l, a_r, b_r, e)`` of the two-part tile rebuild."""
+    k = _split(len(shapes))
+    a_l = math.prod(s[1] for s in shapes[1:k])
+    b_l = math.prod(s[2] for s in shapes[1:k])
+    a_r = math.prod(s[1] for s in shapes[k:])
+    b_r = math.prod(s[2] for s in shapes[k:])
+    return k, a_l, b_l, a_r, b_r, shapes[k][0]
+
+
+def _lane_split_ok(minor: int, outer: int) -> bool:
+    """Splitting or merging a lane dim into ``(outer, minor)``."""
+    return outer == 1 or minor % LANES == 0
+
+
+def layout_ok(shapes: Sequence[tuple]) -> bool:
+    """Can Mosaic lower the in-kernel tile rebuild for these cores?
+
+    Mirrors the reshapes of ``_build_left`` / ``_column_block`` (each must
+    keep the lane dim a multiple of 128 unless it is not split at all) and
+    the x block's ``(bm, I/i1)`` shape (``block_shape_ok``).  Interpret
+    mode accepts any shape; this is what the compiler adds."""
+    shapes = [tuple(s) for s in shapes]
+    if len(shapes) < 2:
+        return False
+    k, a_l, b_l, a_r, b_r, e = _geometry(shapes)
+    i1 = shapes[0][1]
+    i_dim = i1 * a_l * a_r
+    if not block_shape_ok((SUBLANES, a_l * a_r), (2 * SUBLANES, i_dim)):
+        return False
+    cols = 1
+    for (d, a, b, e_k) in shapes[1:k]:
+        if not _lane_split_ok(d, cols) or not _lane_split_ok(b * e_k, a):
+            return False
+        cols *= b
+    return _lane_split_ok(e, b_l) and (a_r % SUBLANES == 0 or a_l == 1)
+
+
 def kernel_eligible(shapes: Sequence[tuple], block_m: int, *,
                     train: bool = False) -> bool:
-    """Can the fused Pallas kernel run these core shapes efficiently?
+    """Can the fused Pallas kernel run these core shapes on the chip?
 
     Two gates, both enforced statically by ``repro.analysis.kernel_budget``:
 
-    * **alignment** — the kernel rebuilds one (I/i1, J/j1) W-tile per
-      program; those tile dims must respect the TPU f32 tiling floor (8
-      sublanes x 128 lanes) or Mosaic pads every tile and the on-chip
-      rebuild loses to plain reconstruct.
+    * **layout** — ``layout_ok``: the compiler accepts the blocks and the
+      in-kernel reshapes of the tile rebuild;
     * **VMEM feasibility** — the program's worst-case residency
       (``kernel_fits``) must clear the per-core budget; some factorizations
       produce W-tiles that alone exceed VMEM (a 13824x1024 f32 tile is 54
       MiB), and compiling those would abort on hardware.
 
-    ``train=True`` additionally requires the backward passes to fit: dL/dx
+    ``train=True`` additionally requires the backward passes to pass: dL/dx
     runs this same kernel over i/j-SWAPPED cores (both orientations must
-    clear the floor) and dL/dcores runs ``_bwd_cores_kernel``.
+    pass) and dL/dcores runs ``_bwd_cores_kernel``.
 
     Used as the *candidate filter* by the autotuner and as the analytic
     gate when no measurement is available.
     """
     shapes = [tuple(s) for s in shapes]
-    ins = [s[1] for s in shapes]
-    outs = [s[2] for s in shapes]
-    i_tile = math.prod(ins[1:])
-    j_tile = math.prod(outs[1:])
-    ok = (block_m % BLOCK_M_ALIGN == 0
-          and i_tile % BLOCK_M_ALIGN == 0 and j_tile % 128 == 0
+    ok = (block_m % BLOCK_M_ALIGN == 0 and layout_ok(shapes)
           and kernel_fits(shapes, block_m))
     if ok and train:
         transposed = [(d0, j, i, d1) for (d0, i, j, d1) in shapes]
-        ok = (j_tile % BLOCK_M_ALIGN == 0 and i_tile % 128 == 0
-              and kernel_fits(transposed, block_m)
+        ok = (layout_ok(transposed) and kernel_fits(transposed, block_m)
               and kernel_fits(shapes, block_m, backward=True))
     return ok
 
@@ -106,48 +157,77 @@ def _effective_block_m(block_m: int, m: int) -> int:
                                          // BLOCK_M_ALIGN))
 
 
+def _tiled(shape: tuple) -> tuple:
+    """A VMEM value's shape padded to Mosaic's (8, 128) tiling."""
+    shape = tuple(shape)
+    if not shape:
+        return shape
+    lanes = -(-shape[-1] // LANES) * LANES
+    if len(shape) == 1:
+        return (lanes,)
+    subl = -(-shape[-2] // SUBLANES) * SUBLANES
+    return shape[:-2] + (subl, lanes)
+
+
+def block_shapes(shapes: Sequence[tuple], block_m: int, m: int, *,
+                 backward: bool = False) -> list:
+    """``(name, block, array)`` for every ``BlockSpec`` of ``_fwd_call`` /
+    ``_bwd_cores_call`` — what ``repro.analysis.kernel_budget`` checks
+    against Mosaic's block-shape rule."""
+    shapes = [tuple(s) for s in shapes]
+    k, a_l, b_l, a_r, b_r, e = _geometry(shapes)
+    _, i1, j1, d1 = shapes[0]
+    bm = _effective_block_m(block_m, m)
+    mp = -(-m // bm) * bm
+    core0 = ((1, 1, 1, d1), (i1, j1, 1, d1))
+    rows = [("core0_fiber", *core0)]
+    rows += [(f"core{t}", shapes[t], shapes[t]) for t in range(1, k)]
+    rows.append(("right", (b_r, e, a_r), (b_r, e, a_r)))
+    rows.append(("x", (bm, a_l * a_r), (mp, i1 * a_l * a_r)))
+    out = ((1, b_r, bm, b_l), (j1, b_r, mp, b_l))
+    if backward:
+        rows.append(("dy", *out))
+        rows.append(("dcore0_fiber", *core0))
+        rows += [(f"dcore{t}", shapes[t], shapes[t]) for t in range(1, k)]
+        rows.append(("dright", (b_r, e, a_r), (b_r, e, a_r)))
+    else:
+        rows.append(("out", *out))
+    return rows
+
+
 def vmem_buffers(shapes: Sequence[tuple], block_m: int, m: int,
                  itemsize: int, *, backward: bool = False) -> list:
     """One program's VMEM-resident buffers: ``(name, shape, bytes_per_elem,
-    pipelined)`` rows.
+    pipelined)`` rows, shapes padded to the (8, 128) VMEM tiling.
 
     MUST mirror the ``BlockSpec``s of ``_fwd_call`` / ``_bwd_cores_call``
-    and the f32 intermediates of the kernel bodies — it lives in this file
-    so the model and the specs change together.  ``repro.analysis.
-    kernel_budget`` sums the rows against the per-core VMEM budget, making
-    a tile that cannot fit a lint error before Mosaic ever sees it.
-    Pipelined rows (blocks whose index map CHANGES across the grid, so the
-    Pallas pipeline double-buffers the HBM↔VMEM stream) cost 2x in
-    residency; constant-index-map blocks (whole cores, revisited
-    accumulators) and kernel-body intermediates are resident once."""
+    (``block_shapes``) and the f32 intermediates of the kernel bodies — it
+    lives in this file so the model and the specs change together.
+    ``repro.analysis.kernel_budget`` sums the rows against the per-core
+    VMEM budget, making a tile that cannot fit a lint error before Mosaic
+    ever sees it.  Pipelined rows (blocks whose index map CHANGES across
+    the grid, so the Pallas pipeline double-buffers the HBM<->VMEM stream)
+    cost 2x in residency; whole-array blocks (constant index maps) and
+    kernel-body intermediates are resident once."""
     shapes = [tuple(s) for s in shapes]
-    ins = [s[1] for s in shapes]
-    outs = [s[2] for s in shapes]
-    i1_blk = math.prod(ins[1:])    # I / i1 — the W-tile's row count
-    j1_blk = math.prod(outs[1:])   # J / j1 — the W-tile's column count
+    k, a_l, b_l, a_r, b_r, e = _geometry(shapes)
     bm = _effective_block_m(block_m, m)
-    d1 = shapes[0][3]
-    bufs = [("core0_fiber", (1, 1, 1, d1), itemsize, True)]
-    for k, s in enumerate(shapes[1:], start=1):
-        bufs.append((f"core{k}", s, itemsize, False))
-    bufs.append(("x", (bm, i1_blk), itemsize, True))
+    bufs = []
+    for name, block, array in block_shapes(shapes, block_m, m,
+                                           backward=backward):
+        isz = 4 if name in ("right", "dright") else itemsize
+        bufs.append((name, _tiled(block), isz, block != array))
+    # f32 values of the kernel body: the left part (and, backward, its
+    # cotangent carry), one column block before and after its relayout,
+    # the upcast x block and the per-column partial product / dW block
+    bufs.append(("left_f32", _tiled((a_l * b_l, e)), 4, False))
+    bufs.append(("col_f32", _tiled((a_l * b_l, a_r)), 4, False))
+    bufs.append(("w_col_f32", _tiled((a_l * a_r, b_l)), 4, False))
+    bufs.append(("x_f32", _tiled((bm, a_l * a_r)), 4, False))
+    bufs.append(("part_f32", _tiled((bm, b_l)), 4, False))
     if backward:
-        bufs.append(("dy", (bm, j1_blk), itemsize, True))
-        bufs.append(("dcore0_fiber", (1, 1, 1, d1), itemsize, True))
-        for k, s in enumerate(shapes[1:], start=1):
-            bufs.append((f"dcore{k}", s, itemsize, False))
-    else:
-        bufs.append(("out", (bm, j1_blk), itemsize, True))
-    # f32 values of the kernel body: the reconstructed W tile (also formed
-    # inside the backward's _tile_w vjp), the upcast x block, and the
-    # partial product / on-chip dW tile
-    bufs.append(("w_tile_f32", (i1_blk, j1_blk), 4, False))
-    bufs.append(("x_f32", (bm, i1_blk), 4, False))
-    if backward:
-        bufs.append(("dy_f32", (bm, j1_blk), 4, False))
-        bufs.append(("dw_tile_f32", (i1_blk, j1_blk), 4, False))
-    else:
-        bufs.append(("part_f32", (bm, j1_blk), 4, False))
+        bufs.append(("dleft_f32", _tiled((a_l * b_l, e)), 4, False))
+        bufs.append(("dw_col_f32", _tiled((a_l * a_r, b_l)), 4, False))
     return bufs
 
 
@@ -164,53 +244,95 @@ def kernel_fits(shapes: Sequence[tuple], block_m: int, *,
     return used <= budget
 
 
-def _tile_w(fiber: jax.Array, rest: list) -> jax.Array:
-    """(I/i1, J/j1) W-tile from core 0's (i1, j1) bond fiber + the remaining
-    cores.  Pure function of VALUES (not refs): the forward kernel calls it
-    on loaded blocks, and the cores-backward kernel pulls the on-chip dW
-    tile back through it with ``jax.vjp``.
-    """
-    ins = [c.shape[1] for c in rest]
-    outs = [c.shape[2] for c in rest]
-    acc = fiber[None, :]                                   # (1, d1)
-    for c in rest:
-        d0 = c.shape[0]
-        acc = acc.reshape(-1, d0) @ c.reshape(d0, -1)
-        acc = acc.reshape(-1, c.shape[-1])
-    # acc rows are (i2,j2,...,in,jn) interleaved; -> (I/i1, J/j1)
-    nr = len(rest)
-    t = acc.reshape([d for k in range(nr) for d in (ins[k], outs[k])])
-    perm = [2 * k for k in range(nr)] + [2 * k + 1 for k in range(nr)]
-    return t.transpose(perm).reshape(math.prod(ins), math.prod(outs))
+# --------------------------------------------------------------------------
+# the two-part tile rebuild (pure functions of VALUES, not refs: the
+# kernels call them on loaded blocks, and the cores-backward pulls
+# cotangents back through them with ``jax.vjp``)
+# --------------------------------------------------------------------------
 
 
-def _load_tile_operands(core_refs, n: int):
-    """(fiber, rest) f32 values for ``_tile_w`` from this program's blocks.
+def _build_left(fiber: jax.Array, lcores: list) -> jax.Array:
+    """``L[(a_l), (b_l, e)]``: core 0's bond fiber chained through the
+    left cores, rows = their i legs, cols = their j legs then the bond."""
+    v = fiber[None, :]                                     # (1, d1)
+    rows = cols = 1
+    for c in lcores:
+        d, a, b, e = c.shape
+        m = v.reshape(rows * cols, d) @ c.reshape(d, a * b * e)
+        if cols == 1:
+            v = m.reshape(rows * a, b * e)
+        else:
+            v = (m.reshape(rows, cols, a, b * e).transpose(0, 2, 1, 3)
+                 .reshape(rows * a, cols * b * e))
+        rows, cols = rows * a, cols * b
+    return v
 
-    core_refs[0] is blocked to (1,1,1,d1) — the (i1,j1) fiber of core 0;
-    the remaining cores are loaded whole (they are small by construction).
-    """
-    fiber = core_refs[0][0, 0, 0, :].astype(jnp.float32)
-    rest = [core_refs[k][...].astype(jnp.float32) for k in range(1, n)]
-    return fiber, rest
+
+def _column_block(left2: jax.Array, r_s: jax.Array, a_l: int,
+                  b_l: int) -> jax.Array:
+    """``W_s[(a_l, a_r), b_l]`` for one trailing output index ``s``."""
+    a_r = r_s.shape[1]
+    t = left2 @ r_s                                        # (a_l*b_l, a_r)
+    return t.reshape(a_l, b_l, a_r).transpose(0, 2, 1).reshape(a_l * a_r,
+                                                               b_l)
 
 
-def _fwd_kernel(*refs, n: int):
-    core_refs = refs[:n]
-    x_ref, o_ref = refs[n], refs[n + 1]
-    fiber, rest = _load_tile_operands(core_refs, n)
-    w_tile = _tile_w(fiber, rest)                          # (I1, J1) f32
-    x_tile = x_ref[...].astype(jnp.float32)                # (bm, I1)
-    part = x_tile @ w_tile                                 # (bm, J1)
-    i = pl.program_id(2)
+def _column_block_t(dw: jax.Array, a_l: int, a_r: int, b_l: int):
+    """Transpose of ``_column_block``'s relayout: ``dW_s -> dT_s``."""
+    return dw.reshape(a_l, a_r, b_l).transpose(0, 2, 1).reshape(a_l * b_l,
+                                                                a_r)
 
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = part.astype(o_ref.dtype)
 
-    @pl.when(i > 0)
-    def _acc():
-        o_ref[...] = (o_ref[...].astype(jnp.float32) + part).astype(o_ref.dtype)
+def _right_block(rcores: list) -> jax.Array:
+    """``R`` arranged ``(b_r, e, a_r)`` in f32: the trailing cores
+    contracted (XLA, once per call — a few KiB)."""
+    r = rcores[0].astype(jnp.float32)                      # (e, a, b, d)
+    for c in rcores[1:]:
+        e0, a0, b0, _ = r.shape
+        _, a, b, d = c.shape
+        r = jnp.einsum("xABd,dabe->xAaBbe", r, c.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST
+                       ).reshape(e0, a0 * a, b0 * b, d)
+    return r[..., 0].transpose(2, 0, 1)
+
+
+def _load_left(core0_ref, lrefs):
+    fiber = core0_ref[0, 0, 0, :].astype(jnp.float32)
+    return fiber, [r[...].astype(jnp.float32) for r in lrefs]
+
+
+def _fwd_kernel(*refs, n_left: int, a_l: int, b_l: int):
+    core0_ref, lrefs = refs[0], refs[1:1 + n_left]
+    r_ref, x_ref, o_ref = refs[1 + n_left:]
+    b_r, e, _ = r_ref.shape
+    left2 = _build_left(*_load_left(core0_ref, lrefs)).reshape(a_l * b_l, e)
+    x_tile = x_ref[...].astype(jnp.float32)                # (bm, I/i1)
+    first = pl.program_id(2) == 0
+
+    def column(s, carry):
+        w_s = _column_block(left2, r_ref[s], a_l, b_l)
+        part = x_tile @ w_s                                # (bm, b_l)
+
+        @pl.when(first)
+        def _init():
+            o_ref[0, s] = part.astype(o_ref.dtype)
+
+        @pl.when(jnp.logical_not(first))
+        def _acc():
+            o_ref[0, s] = (o_ref[0, s].astype(jnp.float32)
+                           + part).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, b_r, column, 0)
+
+
+def _prep(cores: Sequence[jax.Array], m: int, block_m: int):
+    shapes = [tuple(c.shape) for c in cores]
+    k, a_l, b_l, a_r, b_r, e = _geometry(shapes)
+    _, i1, j1, d1 = shapes[0]
+    bm = _effective_block_m(block_m, m)
+    mp = -(-m // bm) * bm
+    return k, a_l, b_l, a_r, b_r, e, i1, j1, d1, bm, mp
 
 
 def _fwd_call(cores: Sequence[jax.Array], x: jax.Array,
@@ -218,44 +340,37 @@ def _fwd_call(cores: Sequence[jax.Array], x: jax.Array,
     """Raw fused forward: ``y[..., J] = x[..., I] @ W(cores)``, W in VMEM
     tiles only."""
     cores = list(cores)
-    n = len(cores)
-    ins = [c.shape[1] for c in cores]
-    outs = [c.shape[2] for c in cores]
-    i_dim = math.prod(ins)
-    j_dim = math.prod(outs)
+    i_dim = math.prod(c.shape[1] for c in cores)
+    j_dim = math.prod(c.shape[2] for c in cores)
     lead = x.shape[:-1]
     m = math.prod(lead) if lead else 1
+    k, a_l, b_l, a_r, b_r, e, i1, j1, d1, bm, mp = _prep(cores, m, block_m)
     xm = x.reshape(m, i_dim)
+    if mp != m:
+        xm = jnp.pad(xm, ((0, mp - m), (0, 0)))
+    right = _right_block(cores[k:])
 
-    bm = _effective_block_m(block_m, m)
-    pad_m = (-m) % bm
-    if pad_m:
-        xm = jnp.pad(xm, ((0, pad_m), (0, 0)))
-    mt = xm.shape[0] // bm
-    i1, j1 = ins[0], outs[0]
-    i1_blk = i_dim // i1
-    j1_blk = j_dim // j1
-
-    in_specs = [pl.BlockSpec((1, 1, 1, cores[0].shape[-1]),
-                             lambda mi, jj, ii: (0, ii, jj, 0))]
-    for c in cores[1:]:
+    in_specs = [pl.BlockSpec((1, 1, 1, d1),
+                             lambda mi, jj, ii: (ii, jj, 0, 0))]
+    for c in cores[1:k]:
         in_specs.append(pl.BlockSpec(c.shape, lambda mi, jj, ii: (0,) * 4))
-    # x blocked over (m, i1): (bm, I/i1)
-    in_specs.append(pl.BlockSpec((bm, i1_blk), lambda mi, jj, ii: (mi, ii)))
-    out_spec = pl.BlockSpec((bm, j1_blk), lambda mi, jj, ii: (mi, jj))
+    in_specs.append(pl.BlockSpec(right.shape, lambda mi, jj, ii: (0,) * 3))
+    in_specs.append(pl.BlockSpec((bm, a_l * a_r),
+                                 lambda mi, jj, ii: (mi, ii)))
+    out_spec = pl.BlockSpec((1, b_r, bm, b_l),
+                            lambda mi, jj, ii: (jj, 0, mi, 0))
 
-    kernel = functools.partial(_fwd_kernel, n=n)
+    kernel = functools.partial(_fwd_kernel, n_left=k - 1, a_l=a_l, b_l=b_l)
     y = pl.pallas_call(
         kernel,
-        grid=(mt, j1, i1),
+        grid=(mp // bm, j1, i1),
         in_specs=in_specs,
         out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((xm.shape[0], j_dim), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((j1, b_r, mp, b_l), x.dtype),
         interpret=interpret,
-    )(*cores, xm)
-    if pad_m:
-        y = y[:m]
-    return y.reshape(*lead, j_dim)
+    )(cores[0].reshape(i1, j1, 1, d1), *cores[1:k], right, xm)
+    y = y.transpose(2, 0, 3, 1).reshape(mp, j_dim)
+    return y[:m].reshape(*lead, j_dim)
 
 
 # --------------------------------------------------------------------------
@@ -263,88 +378,102 @@ def _fwd_call(cores: Sequence[jax.Array], x: jax.Array,
 # --------------------------------------------------------------------------
 
 
-def _bwd_cores_kernel(*refs, n: int):
-    """One (i1, j1) tile of ``dW = x^T dy``, pulled back into core space.
+def _bwd_cores_kernel(*refs, n_left: int, a_l: int, b_l: int):
+    """One (i1, j1, token-block) program of ``dW = x^T dy``, pulled back
+    into core space column block by column block.
 
-    The dW tile exists only in VMEM for the duration of this program; the
-    pullback through ``_tile_w`` (core-chain VJP: a few core-sized matmuls)
-    turns it into per-core gradient contributions which are accumulated
-    across the grid directly into core-shaped outputs.  Grid is
-    ``(i1, j1, M/bm)`` with the token axis innermost: core 0's (i1, j1)
-    gradient block is revisited consecutively over token blocks, and the
-    whole-core outputs (cores 1..n-1) are revisited by every program.
+    No dW column block outlives its loop iteration: each is pulled back
+    into ``dR`` (accumulated in the output) and into the left part's
+    cotangent, which ``jax.vjp`` of ``_build_left`` turns into per-core
+    gradient contributions.  Grid is ``(i1, j1, M/bm)`` with the token axis
+    innermost: core 0's (i1, j1) gradient block is revisited consecutively
+    over token blocks, and the whole-array outputs (left cores, ``dR``)
+    are revisited by every program.
     """
-    core_refs = refs[:n]
-    x_ref, dy_ref = refs[n], refs[n + 1]
-    dcore_refs = refs[n + 2:]
-    fiber, rest = _load_tile_operands(core_refs, n)
-    x_tile = x_ref[...].astype(jnp.float32)                # (bm, I1)
-    dy_tile = dy_ref[...].astype(jnp.float32)              # (bm, J1)
-    dw_tile = x_tile.T @ dy_tile                           # (I1, J1), VMEM-only
-    _, pullback = jax.vjp(_tile_w, fiber, rest)
-    dfiber, drest = pullback(dw_tile)
+    core0_ref, lrefs = refs[0], refs[1:1 + n_left]
+    r_ref, x_ref, dy_ref = refs[1 + n_left:4 + n_left]
+    dcore0_ref = refs[4 + n_left]
+    dl_refs = refs[5 + n_left:5 + 2 * n_left]
+    dr_ref = refs[5 + 2 * n_left]
+    b_r, e, a_r = r_ref.shape
+    left, pullback = jax.vjp(_build_left, *_load_left(core0_ref, lrefs))
+    left2 = left.reshape(a_l * b_l, e)
+    x_tile = x_ref[...].astype(jnp.float32)                # (bm, I/i1)
     mi = pl.program_id(2)
     first = ((pl.program_id(0) == 0) & (pl.program_id(1) == 0) & (mi == 0))
 
-    def accum(ref, val, init):
+    def accum(ref, idx, val, init):
         @pl.when(init)
         def _init():
-            ref[...] = val.astype(ref.dtype)
+            ref[idx] = val.astype(ref.dtype)
 
         @pl.when(jnp.logical_not(init))
         def _acc():
-            ref[...] = (ref[...].astype(jnp.float32) + val).astype(ref.dtype)
+            ref[idx] = (ref[idx].astype(jnp.float32) + val).astype(ref.dtype)
 
-    accum(dcore_refs[0], dfiber.reshape(1, 1, 1, -1), mi == 0)
-    for k in range(1, n):
-        accum(dcore_refs[k], drest[k - 1], first)
+    def column(s, dleft2):
+        r_s = r_ref[s]                                     # (e, a_r)
+        dy_s = dy_ref[0, s].astype(jnp.float32)            # (bm, b_l)
+        dw = x_tile.T @ dy_s                               # (I/i1, b_l)
+        dt = _column_block_t(dw, a_l, a_r, b_l)            # (a_l*b_l, a_r)
+        accum(dr_ref, s, left2.T @ dt, first)
+        return dleft2 + dt @ r_s.T
+
+    dleft2 = jax.lax.fori_loop(0, b_r, column,
+                               jnp.zeros((a_l * b_l, e), jnp.float32))
+    dfiber, dlcores = pullback(dleft2.reshape(a_l, b_l * e))
+    accum(dcore0_ref, (0, 0, 0, slice(None)), dfiber, mi == 0)
+    for ref, val in zip(dl_refs, dlcores):
+        accum(ref, ..., val, first)
 
 
 def _bwd_cores_call(cores: list, x: jax.Array, dy: jax.Array,
                     block_m: int, interpret: bool) -> tuple:
     """Per-core gradients of ``sum(dy * (x @ W(cores)))`` — dense dW is
-    never materialized (one VMEM tile at a time)."""
-    n = len(cores)
-    ins = [c.shape[1] for c in cores]
-    outs = [c.shape[2] for c in cores]
-    i_dim = math.prod(ins)
-    j_dim = math.prod(outs)
+    never materialized (one VMEM column block at a time)."""
+    cores = list(cores)
+    i_dim = math.prod(c.shape[1] for c in cores)
+    j_dim = math.prod(c.shape[2] for c in cores)
     m = math.prod(x.shape[:-1]) if x.ndim > 1 else 1
+    k, a_l, b_l, a_r, b_r, e, i1, j1, d1, bm, mp = _prep(cores, m, block_m)
     xm = x.reshape(m, i_dim)
     dym = dy.reshape(m, j_dim)
-
-    bm = _effective_block_m(block_m, m)
-    pad_m = (-m) % bm
-    if pad_m:
+    if mp != m:
         # zero rows contribute nothing to x^T dy
-        xm = jnp.pad(xm, ((0, pad_m), (0, 0)))
-        dym = jnp.pad(dym, ((0, pad_m), (0, 0)))
-    mt = xm.shape[0] // bm
-    i1, j1 = ins[0], outs[0]
-    i1_blk = i_dim // i1
-    j1_blk = j_dim // j1
+        xm = jnp.pad(xm, ((0, mp - m), (0, 0)))
+        dym = jnp.pad(dym, ((0, mp - m), (0, 0)))
+    dym = dym.reshape(mp, j1, b_l, b_r).transpose(1, 3, 0, 2)
+    right, right_vjp = jax.vjp(_right_block, cores[k:])
 
-    in_specs = [pl.BlockSpec((1, 1, 1, cores[0].shape[-1]),
-                             lambda ii, jj, mi: (0, ii, jj, 0))]
-    for c in cores[1:]:
-        in_specs.append(pl.BlockSpec(c.shape, lambda ii, jj, mi: (0,) * 4))
-    in_specs.append(pl.BlockSpec((bm, i1_blk), lambda ii, jj, mi: (mi, ii)))
-    in_specs.append(pl.BlockSpec((bm, j1_blk), lambda ii, jj, mi: (mi, jj)))
-    out_specs = [pl.BlockSpec((1, 1, 1, cores[0].shape[-1]),
-                              lambda ii, jj, mi: (0, ii, jj, 0))]
-    for c in cores[1:]:
-        out_specs.append(pl.BlockSpec(c.shape, lambda ii, jj, mi: (0,) * 4))
+    fiber_spec = pl.BlockSpec((1, 1, 1, d1),
+                              lambda ii, jj, mi: (ii, jj, 0, 0))
+    whole = [pl.BlockSpec(c.shape, lambda ii, jj, mi: (0,) * 4)
+             for c in cores[1:k]]
+    right_spec = pl.BlockSpec(right.shape, lambda ii, jj, mi: (0,) * 3)
+    in_specs = [fiber_spec, *whole, right_spec,
+                pl.BlockSpec((bm, a_l * a_r), lambda ii, jj, mi: (mi, ii)),
+                pl.BlockSpec((1, b_r, bm, b_l),
+                             lambda ii, jj, mi: (jj, 0, mi, 0))]
+    out_specs = [fiber_spec, *whole, right_spec]
+    out_shape = ([jax.ShapeDtypeStruct((i1, j1, 1, d1), cores[0].dtype)]
+                 + [jax.ShapeDtypeStruct(c.shape, c.dtype)
+                    for c in cores[1:k]]
+                 + [jax.ShapeDtypeStruct(right.shape, jnp.float32)])
 
-    kernel = functools.partial(_bwd_cores_kernel, n=n)
-    dcores = pl.pallas_call(
+    kernel = functools.partial(_bwd_cores_kernel, n_left=k - 1, a_l=a_l,
+                               b_l=b_l)
+    outs = pl.pallas_call(
         kernel,
-        grid=(i1, j1, mt),
+        grid=(i1, j1, mp // bm),
         in_specs=in_specs,
         out_specs=out_specs,
-        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in cores],
+        out_shape=out_shape,
         interpret=interpret,
-    )(*cores, xm, dym)
-    return tuple(dcores)
+    )(cores[0].reshape(i1, j1, 1, d1), *cores[1:k], right, xm, dym)
+    dcore0, dleft, dright = outs[0], outs[1:k], outs[k]
+    (drcores,) = right_vjp(dright)
+    return (dcore0.reshape(cores[0].shape), *dleft,
+            *(d.astype(c.dtype) for d, c in zip(drcores, cores[k:])))
 
 
 # --------------------------------------------------------------------------
@@ -388,7 +517,7 @@ def mpo_linear(cores: Sequence[jax.Array], x: jax.Array, *,
     transposed cores).  ``interpret`` is REQUIRED: the caller (normally the
     execution engine via ``kernels.ops``) decides whether the kernel bodies
     run compiled on TPU (``False``) or interpreted in Python on CPU
-    (``True``, correctness-only).
+    (``True``, correctness-only) — ``kernels.tpu.interpret_mode()``.
 
     ``block_m`` must be a positive multiple of ``BLOCK_M_ALIGN`` (the f32
     sublane count — unaligned tile heights make Mosaic pad every x/out

@@ -143,8 +143,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, mpo=True,
     t1 = time.time()
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     hlo = hlo_analyze(compiled.as_text())
     n_dev = mesh.devices.size
     rec = {

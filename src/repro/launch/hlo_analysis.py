@@ -98,9 +98,8 @@ class HloModule:
                         break
                 if depth >= 1:
                     buf += ch
-            # newer XLA prints operands with inline types
-            # ("f32[16,256]{1,0} %h.1"); the name is the last token
-            operands = [a.strip().split()[-1].lstrip("%")
+            # operands print as bare names ("dot(%a.1, %b.1)")
+            operands = [a.strip().lstrip("%")
                         for a in _split_top(buf) if a.strip()]
             self.comps[cur].append({
                 "name": name, "type": type_str, "op": op,

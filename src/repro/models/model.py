@@ -41,9 +41,14 @@ class Model:
         """Serving-time weight cache: contract decode-``cached`` matrices to
         dense W once (done at serving init, next to the KV cache).  With
         ``axes`` returns ``(params, axes)`` — the dense W inherits the cores'
-        TP layout (see ``MPOEngine.cache_weights``)."""
+        TP layout (see ``MPOEngine.cache_weights``).
+
+        W is stored in the activation dtype: every use casts it there
+        anyway (``MPOEngine.linear`` / ``embedding``), so a wider copy
+        would only double the snapshot's device memory."""
         from repro.core.engine import engine_for
-        return engine_for(self.cfg.mpo).cache_weights(params, axes=axes)
+        return engine_for(self.cfg.mpo).cache_weights(
+            params, axes=axes, dtype=self.cfg.jnp_dtype)
 
 
 def build(cfg: ModelConfig) -> Model:

@@ -7,10 +7,12 @@ functions consume plain value trees (post ``split_annotations``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import layers as L
 from repro.core.layers import Annot, MPOConfig
@@ -261,8 +263,7 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
     through the ``gather_pages`` contiguous view, masked by the caller's
     offset-aware mask — token-identical to an unchunked prefill."""
     from repro.kernels import decode_attention as DA
-    from repro.kernels import ops
-    from repro.parallel.ctx import shard_dims
+    from repro.parallel.ctx import get_mesh, shard_dims
     b, s = q.shape[0], q.shape[1]
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kvh
@@ -279,24 +280,40 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
     else:                                          # single-token decode
         new_cache = _paged_decode_append(cache, k, v)
         kp, vp = new_cache["k_pages"], new_cache["v_pages"]
-        # pin the paged flash layout (in-page seq dim over model) so GSPMD
-        # never reshards the pool per layer — mirror of the dense pin below
-        kp = shard_dims(kp, {1: "model"})
-        vp = shard_dims(vp, {1: "model"})
+        # pin the paged layout (``sharding.cache_sharding``) so GSPMD never
+        # reshards the pool per layer: KV heads over model where the axis
+        # divides them (each device attends its own heads), else the
+        # in-page sequence dim
+        mesh = get_mesh()
+        model = dict(zip(mesh.axis_names, mesh.devices.shape)).get(
+            "model", 1) if mesh is not None else 1
+        pin = {2: "model"} if kvh % model == 0 else {1: "model"}
+        kp, vp = shard_dims(kp, pin), shard_dims(vp, pin)
         new_cache = dict(new_cache, k_pages=kp, v_pages=vp)
         table = new_cache["page_table"]
         ps, mp = kp.shape[1], table.shape[1]
-        impl = DA.choose_impl(kvh, g, dh, ps, mp, str(q.dtype),
-                              interpret=ops.INTERPRET)
+        # the kernel runs per device over its own KV heads (a Mosaic kernel
+        # is never partitioned automatically); with heads the model axis
+        # does not divide, the XLA gather is the only path
+        impl = (DA.choose_impl(kvh, g, dh, ps, mp, str(q.dtype))
+                if kvh % model == 0 else "xla")
         y = None
         if impl == "flash":
             lengths = jnp.minimum(new_cache["pos"], mp * ps).astype(jnp.int32)
             bias = jnp.where(mask[:, 0, 0], 0.0, DA.MASK_VALUE
                              ).astype(jnp.float32)
+            flash = functools.partial(DA.flash_decode_attention,
+                                      softcap=cfg.attn_softcap)
+            if model > 1:
+                heads = P(None, "model", None, None)
+                pages = P(None, None, "model", None)
+                flash = jax.shard_map(
+                    flash, mesh=mesh, in_specs=(heads, pages, pages, P(),
+                                                P(), P()),
+                    out_specs=heads, check_vma=False)
             try:
-                y = DA.flash_decode_attention(
-                    q[:, 0].reshape(b, kvh, g, dh), kp, vp, table, lengths,
-                    bias, softcap=cfg.attn_softcap, interpret=ops.INTERPRET)
+                y = flash(q[:, 0].reshape(b, kvh, g, dh), kp, vp, table,
+                          lengths, bias)
                 y = y[:, None]                     # (B, 1, KV, G, Dh)
             except Exception as e:                 # noqa: BLE001
                 # Pallas failures surface at trace/lowering time; degrade

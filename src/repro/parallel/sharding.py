@@ -168,10 +168,11 @@ def cache_sharding(cache_specs, mesh: Mesh, rules: dict):
     every page mapping for the gather.
 
     Paged KV leaves (``k_pages``/``v_pages``: (L, pages, page_size, KV,
-    Dh)) get the paged flash layout: the in-page sequence dim over
-    ``model`` (the analog of the dense cache's seq-over-model), the
-    physical page dim UNsharded — pages are slot-agnostic, so splitting
-    the pool over data devices would turn every table-indexed gather into
+    Dh)) get the paged flash layout: the KV-head dim over ``model`` where
+    the axis divides it (each device's flash kernel attends its own heads;
+    ``nn._paged_attention``), else the in-page sequence dim; the physical
+    page dim UNsharded — pages are slot-agnostic, so splitting the pool
+    over data devices would turn every table-indexed gather into
     cross-device traffic."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     b = rules["batch"]
@@ -181,10 +182,10 @@ def cache_sharding(cache_specs, mesh: Mesh, rules: dict):
 
     def paged_leaf(sd):
         parts = [None] * len(sd.shape)
-        if sd.shape[2] % mprod == 0:
-            parts[2] = "model"
-        elif sd.shape[3] % mprod == 0:
+        if sd.shape[3] % mprod == 0:
             parts[3] = "model"
+        elif sd.shape[2] % mprod == 0:
+            parts[2] = "model"
         return NamedSharding(mesh, P(*parts))
 
     def one_with_path(path, sd):

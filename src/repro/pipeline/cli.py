@@ -211,6 +211,8 @@ def _run(args) -> int:
 
 def main(argv=None):
     argv = sys.argv[1:] if argv is None else list(argv)
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     if argv and argv[0] in ("tune-export", "tune-import"):
         return _tune_main(argv)
     if argv and argv[0] == "serve-replay":

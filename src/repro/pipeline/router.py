@@ -136,8 +136,9 @@ class PoolRouter:
     """Route/retry/trip/shed across ``ServePool`` replicas (module doc).
 
     ``pools`` must share geometry (slots, max_len, paged) and weights —
-    ``Session.serve_fleet`` is the supported constructor.  ``rebuild_fn``
-    returns a FRESH replacement pool (from the session's saved weights);
+    ``Session.serve_fleet`` is the supported constructor.
+    ``rebuild_fn(idx)`` returns a FRESH replacement pool for replica
+    ``idx`` (from the session's saved weights, on that replica's device);
     without one a tripped/killed replica goes ``dead`` and never rejoins.
     Share ``clock`` with the pools and the replay loop."""
 
@@ -379,7 +380,7 @@ class PoolRouter:
             rep.state = DEAD
             rep.pool = None if killed else rep.pool
             return
-        rep.pool = self._rebuild_fn()
+        rep.pool = self._rebuild_fn(rep.idx)
         rep.rebuilds += 1
         self._rebuilds += 1
         rep.state = OPEN

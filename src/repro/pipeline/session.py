@@ -40,6 +40,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh
 
 from repro import configs
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -49,6 +50,7 @@ from repro.core.engine import engine_for
 from repro.data.pipeline import SyntheticCLS, make_batch_fn
 from repro.models import model as M
 from repro.optim import optimizers, schedule
+from repro.runtime import enable_compile_cache
 from repro.train.loop import LoopConfig, run_training
 from repro.train.steps import (TrainState, lm_loss, make_cls_loss,
                                make_serve_steps, make_train_step)
@@ -155,6 +157,7 @@ class Session:
     """
 
     def __init__(self, cfg: ModelConfig, params, axes=None):
+        enable_compile_cache()
         self.cfg = cfg
         self.model = M.build(cfg)
         self.engine = engine_for(cfg.mpo)
@@ -592,6 +595,11 @@ class Session:
         fleet serving exactly what the others serve.  Without it, rebuilds
         re-snapshot this live session's weights instead.
 
+        With more than one device (and no ``mesh`` in ``pool_kw``), replica
+        ``i`` lives on device ``i % device_count``: its weight snapshot and
+        KV cache are placed through ``serve_pool(mesh=...)`` with a
+        one-device mesh, and its rebuilds land on the same device.
+
         ``router`` kwargs pass through to ``PoolRouter`` (``retry_limit``,
         ``breaker_failures``, ``breaker_cooldown_s``, ``shed_queue_depth``,
         ...); ``pool_kw`` to every ``serve_pool`` replica.  All replicas,
@@ -607,19 +615,26 @@ class Session:
         if replicas < 1:
             raise ValueError(f"replicas={replicas} must be >= 1")
         clock = WallClock() if clock is None else clock
-        pools = [self.serve_pool(slots, max_len, clock=clock, **pool_kw)
-                 for _ in range(replicas)]
+        devices = jax.devices()
+
+        def replica_kw(idx: int) -> dict:
+            if "mesh" in pool_kw or len(devices) == 1:
+                return dict(pool_kw, clock=clock)
+            dev = devices[idx % len(devices)]
+            mesh = Mesh(np.array([dev]).reshape(1, 1), ("data", "model"))
+            return dict(pool_kw, clock=clock, mesh=mesh)
+
+        pools = [self.serve_pool(slots, max_len, **replica_kw(i))
+                 for i in range(replicas)]
         if session_dir is not None:
             self.save(session_dir)
 
-            def rebuild():
+            def rebuild(idx: int):
                 restored = Session.restore(session_dir)
-                return restored.serve_pool(slots, max_len, clock=clock,
-                                           **pool_kw)
+                return restored.serve_pool(slots, max_len, **replica_kw(idx))
         else:
-            def rebuild():
-                return self.serve_pool(slots, max_len, clock=clock,
-                                       **pool_kw)
+            def rebuild(idx: int):
+                return self.serve_pool(slots, max_len, **replica_kw(idx))
         return PoolRouter(pools, rebuild_fn=rebuild, clock=clock,
                           **(router or {}))
 

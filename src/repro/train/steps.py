@@ -234,6 +234,11 @@ def make_serve_steps(model: Model, *, weight_cache: bool = True,
 
     def init_serve_mesh(params, batch: int, max_len: int):
         cache = model.init_cache(batch, max_len, **cache_kw)
+        # contract the dense snapshot on the mesh's own devices, not on the
+        # default device: a fleet replica's weights never pass through
+        # another replica's chip
+        params = jax.device_put(
+            params, S.tree_shardings(axes, _specs(params), mesh, rules))
         if weight_cache:
             serve_params, serve_axes = model.cache_weights(params, axes=axes)
         else:

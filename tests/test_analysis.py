@@ -123,6 +123,36 @@ def test_decode_attention_geometry_checks():
             and f.severity == "error"]
 
 
+def test_seeded_block_shape_violations_reported():
+    """The geometries the TPU compiler refused before the kernels were
+    repaired — core 0's (1, 1, 1, d1) fiber block over (1, i1, j1, d1), and
+    a one-KV-head page block (1, ps, 1, Dh) — are block-shape errors; the
+    kernels' real geometries at qwen3-14b widths are clean."""
+    wq = ((1, 5, 5, 25), (25, 8, 8, 128), (128, 8, 8, 128), (128, 4, 4, 16),
+          (16, 4, 4, 1))
+
+    def fiber_blocks(shapes, bm, m, backward=False):
+        _, i1, j1, d1 = shapes[0]
+        return [("core0_fiber", (1, 1, 1, d1), (1, i1, j1, d1))]
+
+    found = KB.lint_mpo_call(wq, config="qwen3-14b", blocks_fn=fiber_blocks)
+    errs = [f for f in found if f.check == "kernel/block-shape"]
+    assert errs and all(f.severity == "error" for f in errs)
+    assert all(f.location.endswith(":core0_fiber") for f in errs)
+    real = KB.lint_mpo_call(wq, config="qwen3-14b")
+    assert not [f for f in real if f.check == "kernel/block-shape"]
+
+    def one_head_pages(b, kv, g, dh, ps, mp, pool):
+        return [("k_page", (1, ps, 1, dh), (pool, ps, kv, dh))]
+
+    found = KB.lint_decode_attention_call(8, 5, 128, 16, 16, config="x",
+                                          blocks_fn=one_head_pages)
+    assert [f.location for f in found if f.check == "kernel/block-shape"] \
+        == ["kv=8,g=5,dh=128,ps=16,mp=16:k_page"]
+    real = KB.lint_decode_attention_call(8, 5, 128, 16, 16, config="x")
+    assert not [f for f in real if f.check == "kernel/block-shape"]
+
+
 def test_kernel_constants_tripwire():
     assert KB.lint_constants() == []
 

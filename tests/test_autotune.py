@@ -215,3 +215,22 @@ def test_key_includes_jax_version(tuned_env, monkeypatch):
     entries = json.load(open(tuned_env))["entries"]
     assert old_key in entries
     assert autotune.make_key(SHAPES, TOKENS, "prefill", "float32") in entries
+
+
+# qwen3-14b's vocabulary head at published widths (5120 -> 152064)
+HEAD = ((1, 5, 16, 64), (64, 8, 11, 64), (64, 8, 12, 64), (64, 4, 12, 24),
+        (24, 4, 6, 1))
+
+
+def test_chain_raced_only_where_its_flops_allow():
+    """The factorized chain is a race candidate only where its FLOPs do not
+    exceed rebuild + dense: at a 512-token train step the head's chain
+    (3x the dense FLOPs/token) is not timed; at one token it is."""
+    assert not autotune.chain_in_race(HEAD, 512)
+    assert autotune.chain_in_race(HEAD, 1)
+    labels = [lbl for lbl, _ in autotune._candidates(
+        HEAD, 512, "train", "bfloat16", True)]
+    assert labels == ["reconstruct"]
+    labels = [lbl for lbl, _ in autotune._candidates(
+        SHAPES, 1, "prefill", "float32", True)]
+    assert "factorized" in labels

@@ -28,7 +28,8 @@ def test_hlo_analysis_calibration():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.hlo_analysis import analyze
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = jax.make_mesh((4, 4), ("data", "model"),
+                             (jax.sharding.AxisType.Auto,) * 2)
         B, K, L = 64, 256, 8
         def g(x, ws):
             def body(h, w):

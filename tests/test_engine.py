@@ -132,10 +132,12 @@ def test_embedding_parity_cached_vs_factorized():
 # ------------------------------------------------- pinned plan decisions
 
 
-# hand-built MXU-aligned 5-core chain: I = J = 4^5 = 1024, W-tile
-# (I/i1, J/j1) = (256, 256) — multiples of (8, 128)
-ALIGNED = ((1, 4, 4, 64), (64, 4, 4, 64), (64, 4, 4, 64), (64, 4, 4, 64),
-           (64, 4, 4, 1))
+# qwen3-14b's attention cores at published widths: the K/V projection
+# (5120 -> 1024) and the query projection (5120 -> 5120)
+WK = ((1, 5, 4, 20), (20, 8, 4, 128), (128, 8, 4, 128), (128, 4, 4, 16),
+      (16, 4, 4, 1))
+WQ = ((1, 5, 5, 25), (25, 8, 8, 128), (128, 8, 8, 128), (128, 4, 4, 16),
+      (16, 4, 4, 1))
 
 
 def test_plan_phase_decisions_pinned():
@@ -145,31 +147,29 @@ def test_plan_phase_decisions_pinned():
     ffn = tuple(mpo.MPOSpec.make(1024, 1024, n=5, bond_dim=16).core_shapes())
     vocab = tuple(mpo.MPOSpec.make(32768, 256, n=3, bond_dim=8).core_shapes())
 
-    # train on TPU: dense-favored + aligned -> the kernel, now that it has a
-    # fused VJP (core-space gradient accumulation) — the acceptance contract
-    assert choose_mode(cfg, ffn, 4096, "train", interpret=False)[0] \
-        == "kernel"
-    assert choose_mode(cfg, ALIGNED, 4096, "train", interpret=False)[0] \
-        == "kernel"
+    # train on TPU: dense-favored with a tile the compiler accepts in both
+    # orientations and in the cores-backward -> the kernel, at the largest
+    # such tile
+    plan = MPOEngine(cfg, interpret=False).plan(WK, 4096, "train")
+    assert (plan.mode, plan.block_m) == ("kernel", 64)
+    # the query projection's cores-backward exceeds VMEM at every tile
+    assert choose_mode(cfg, WQ, 4096, "train", interpret=False)[0] \
+        == "reconstruct"
     # train in interpret mode: kernel never a perf candidate -> reconstruct
     # (matmul_reconstruct's core-space backward)
-    assert choose_mode(cfg, ffn, 4096, "train", interpret=True)[0] \
+    assert choose_mode(cfg, WK, 4096, "train", interpret=True)[0] \
         == "reconstruct"
-    # prefill on TPU (interpret=False) with aligned tiles -> fused kernel
-    assert choose_mode(cfg, ffn, 4096, "prefill", interpret=False)[0] \
+    # prefill on TPU (interpret=False) with a compilable tile -> fused kernel
+    assert choose_mode(cfg, WQ, 4096, "prefill", interpret=False)[0] \
         == "kernel"
-    assert choose_mode(cfg, ALIGNED, 4096, "prefill", interpret=False)[0] \
+    assert choose_mode(cfg, WK, 4096, "prefill", interpret=False)[0] \
         == "kernel"
     # interpreter mode is never a perf candidate -> falls back to reconstruct
-    assert choose_mode(cfg, ffn, 4096, "prefill", interpret=True)[0] \
+    assert choose_mode(cfg, WQ, 4096, "prefill", interpret=True)[0] \
         == "reconstruct"
-    # one-sided alignment (j-tile 128-aligned, i-tile only 8-aligned) is
-    # prefill-only: train's dL/dx pass runs the kernel over TRANSPOSED
-    # cores, whose j-tile would be 16 — below the 128-lane floor
-    oneside = ((1, 2, 4, 32), (32, 4, 4, 32), (32, 4, 32, 1))
-    assert choose_mode(cfg, oneside, 4096, "prefill", interpret=False)[0] \
-        == "kernel"
-    assert choose_mode(cfg, oneside, 4096, "train", interpret=False)[0] \
+    # bonds narrower than the 128 lanes make the in-kernel tile rebuild
+    # unlowerable: no kernel at any tile
+    assert choose_mode(cfg, ffn, 4096, "prefill", interpret=False)[0] \
         == "reconstruct"
     # decode: dense/token beats the chain for ffn-like shapes -> cached
     assert choose_mode(cfg, ffn, 8, "decode", interpret=True)[0] == "cached"
@@ -196,8 +196,8 @@ def test_plan_respects_forced_mode_and_rejects_bad_phase():
 
 def test_plans_are_memoized():
     eng = engine_for(AUTO)
-    p1 = eng.plan(ALIGNED, 4096, "prefill")
-    p2 = eng.plan([list(s) for s in ALIGNED], 4096, "prefill")
+    p1 = eng.plan(WQ, 4096, "prefill")
+    p2 = eng.plan([list(s) for s in WQ], 4096, "prefill")
     assert p1 is p2  # same plan object: planned once per signature
     assert engine_for(AUTO) is eng
 

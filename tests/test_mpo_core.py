@@ -228,9 +228,15 @@ def test_prop_tt_round_never_increases_params(seed, bond):
 @settings(max_examples=8, deadline=None)
 @given(st.integers(0, 4))
 def test_prop_reconstruct_stagings_agree(seed):
-    """Legs-leading and merged chain stagings are numerically identical."""
+    """The right-to-left chain equals the interleaved left-to-right
+    staging: all cores contracted over their bonds into
+    ``(i1, j1, ..., in, jn)``, then de-interleaved to ``(I, J)``."""
     m = _rand((24, 40), seed + 9)
     cores, _ = mpo.decompose(m, mpo.MPOSpec.make(24, 40, n=4, bond_dim=5))
+    acc = cores[0].reshape(-1, cores[0].shape[-1])
+    for c in cores[1:]:
+        acc = (acc @ c.reshape(c.shape[0], -1)).reshape(-1, c.shape[-1])
+    t = acc.reshape([d for c in cores for d in c.shape[1:3]])
+    t = t.transpose([0, 2, 4, 6, 1, 3, 5, 7]).reshape(24, 40)
     np.testing.assert_allclose(np.asarray(mpo.reconstruct(cores)),
-                               np.asarray(mpo.reconstruct_merged(cores)),
-                               atol=1e-5)
+                               np.asarray(t), atol=1e-5)

@@ -75,7 +75,8 @@ def test_rules_divisibility_fallback():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.parallel import sharding as S
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             (jax.sharding.AxisType.Auto,) * 2)
         rules = S.make_rules(mesh, fsdp=False)
         # divisible dim -> sharded; non-divisible -> replicated
         assert S.spec_for(("ffn",), (16,), rules, mesh) == P("model")
@@ -140,7 +141,8 @@ def test_sp_lowering_small_mesh():
         from repro.parallel.ctx import current_mesh, sequence_parallel
         from repro.train.steps import TrainState, make_train_step
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             (jax.sharding.AxisType.Auto,) * 2)
         cfg = configs.smoke_config("qwen3-14b", d_model=64, num_heads=4,
                                    num_kv_heads=2, parallelism="sp")
         shape = ShapeConfig("t", "train", 32, 4)
@@ -177,13 +179,15 @@ def test_elastic_checkpoint_reshard():
         from repro.checkpoint.manager import CheckpointManager
 
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-        mesh1 = jax.make_mesh((8,), ("data",))
+        mesh1 = jax.make_mesh((8,), ("data",),
+                              (jax.sharding.AxisType.Auto,))
         t1 = jax.tree.map(lambda a: jax.device_put(
             a, NamedSharding(mesh1, P("data"))), tree)
         with tempfile.TemporaryDirectory() as d:
             mgr = CheckpointManager(d, async_save=False)
             mgr.save(1, t1)
-            mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+            mesh2 = jax.make_mesh((2, 4), ("data", "model"),
+                                  (jax.sharding.AxisType.Auto,) * 2)
             sh2 = {"w": NamedSharding(mesh2, P("data", "model"))}
             t2, meta = mgr.restore(1, tree, shardings=sh2)
             assert t2["w"].sharding == sh2["w"]

@@ -151,14 +151,24 @@ def test_mesh_pool_recycling_matches_serial():
     assert "MESH_POOL_OK" in r.stdout, r.stdout[-2000:] + r.stderr[-2000:]
 
 
-def test_mesh_pool_paged_matches_serial():
+@pytest.mark.parametrize("model,impl,pages_spec", [
+    # 2 KV heads over model=4: in-page seq over model, the XLA gather
+    (4, "", (None, None, "model")),
+    # 2 KV heads over model=2: heads over model, the flash kernel per device
+    (2, "flash", (None, None, None, "model")),
+])
+def test_mesh_pool_paged_matches_serial(model, impl, pages_spec):
     """Paged KV over the 8-device mesh: the pool decodes through the paged
-    cache (page data in the paged flash layout — in-page seq over model,
-    table/free-list leaves replicated) and still produces exactly the
-    serial batch-1 tokens, with every page returned on drain."""
-    code = _PRELUDE + """
+    cache (page data in the paged flash layout — KV heads over model where
+    the axis divides them, else in-page seq; table/free-list leaves
+    replicated) and still produces exactly the serial batch-1 tokens, with
+    every page returned on drain and no flash fallback."""
+    code = _PRELUDE + f"""
+    model, impl, pages_spec = {model}, {impl!r}, {pages_spec!r}
+    """ + """
+    os.environ["REPRO_DECODE_ATTN"] = impl
     s = Session.init("qwen3-14b")
-    mesh = make_host_mesh(model=4)
+    mesh = make_host_mesh(model=model)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 500, size=p).astype(np.int32)
                for p in (8, 5, 11)]
@@ -177,10 +187,11 @@ def test_mesh_pool_paged_matches_serial():
                                       err_msg=f"request {i}")
     st = pool.stats()
     assert st["completed"] == 3 and st["page_pool"]["used"] == 0
-    # paged flash layout on the mesh: in-page seq dim over model, page
-    # table / free list / positions replicated
+    assert st["flash_fallbacks"] == 0
+    # paged flash layout on the mesh; page table / free list / positions
+    # replicated
     kp = pool._cache["k_pages"]
-    assert kp.sharding.spec == P(None, None, "model"), kp.sharding.spec
+    assert kp.sharding.spec == P(*pages_spec), kp.sharding.spec
     assert pool._cache["page_table"].sharding.spec == P()
     assert pool._cache["free_list"].sharding.spec == P()
     print("MESH_PAGED_OK")

@@ -182,7 +182,8 @@ def test_sharded_train_step_small_mesh():
         from repro.train.steps import TrainState, make_train_step
         from repro.parallel.ctx import current_mesh
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             (jax.sharding.AxisType.Auto,) * 2)
         cfg = configs.smoke_config("qwen3-14b", d_model=64, num_heads=4,
                                    num_kv_heads=2)
         shape = ShapeConfig("t", "train", 32, 8)
