@@ -1,0 +1,10 @@
+"""Serving steps: mean device time per call of the chunk-prefill program
+(``jit_prefill_chunk_step``), from the profiler trace."""
+
+from bench.trace import program_calls
+
+
+def read(obs):
+    tr = obs.get("trace")
+    calls = program_calls(tr, "prefill_chunk_step") if tr else []
+    return 1e3 * sum(calls) / len(calls) if calls else None
