@@ -50,8 +50,6 @@ def _measure(session, trace, **pool_kw) -> dict:
     out = dict(report.summary)
     out.update({
         "prefill_traces": st["prefill_traces"],
-        "prefill_toks_s": st["prefill_toks_s"],
-        "decode_toks_s": st["decode_toks_s"],
         "occupancy": round(st["occupancy"], 4),
     })
     return out

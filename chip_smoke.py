@@ -211,8 +211,7 @@ def phase_serve(session, meter, seed: int) -> dict:
         failures=stats["failures"], build_s=round(build, 3),
         seconds=round(time.perf_counter() - t0, 3),
         decode_steps=stats["decode_steps"],
-        decode_seconds=stats["decode_seconds"],
-        admit_seconds=stats["admit_seconds"],
+        phases=stats["phases"],
         prefill_traces=stats.get("prefill_traces"), **meter.since(m0))
     check(all(r.status == "done" and len(r.tokens) == NEW_TOKENS
               for r in reqs), f"not every request done: "

@@ -163,8 +163,7 @@ def _replay_main(argv) -> int:
         }
     else:
         out.update(prefill_traces=stats["prefill_traces"],
-                   prefill_toks_s=stats["prefill_toks_s"],
-                   decode_toks_s=stats["decode_toks_s"],
+                   phases=stats["phases"],
                    occupancy=round(stats["occupancy"], 4))
     print(json.dumps(out, indent=2))
     return 0

@@ -13,8 +13,15 @@ each row at its OWN offset.  ``ServePool`` is the scheduler on top:
 * every ``step()`` runs ONE batched decode over all slots; finished rows
   (budget exhausted or EOS emitted) free their slot, which the next
   admission recycles;
-* ``stats()`` reports slot occupancy and aggregate tokens/s —
+* ``stats()`` reports slot occupancy, token and request counts, and the
+  host time of each phase of ``step()`` (``"phases"``) —
   ``Session.report()`` surfaces it for every pool the session created.
+
+Each phase of ``step()`` is a profiler span ``pool.<phase>`` on the host
+timeline, nested in ``pool.step`` and on the clock the device trace uses,
+and an always-on counter (count, total and longest seconds) under
+``stats()["phases"]`` (``pipeline/spans.py``).  ``docs/serving.md`` lists
+the phases.
 
 The aggregate win is the usual continuous-batching one: a decode step over
 ``k`` live slots costs roughly the same wall time as over one, so serving
@@ -67,8 +74,9 @@ keys on it) and the human-readable ``.error_detail``, and aggregated in
 counters in ``stats()["fail_reasons"]`` stay exact forever).
 
 Time comes from an injectable clock (``pipeline.clock``): deadlines,
-budgets and ``submitted_at`` all read ``clock.now()``, so tests pin expiry
-behavior on a ``VirtualClock`` instead of sleeping.
+budgets and the request stamps ``submitted_at``, ``admitted_at`` and
+``first_token_at`` all read ``clock.now()``, so tests pin expiry behavior
+on a ``VirtualClock`` instead of sleeping.
 
 Example::
 
@@ -76,7 +84,7 @@ Example::
     for p in prompts:                       # independent tenants
         pool.submit(p, max_new_tokens=16)
     outputs = pool.run()                    # {rid: np.ndarray of token ids}
-    print(pool.stats()["tok_per_s"])
+    print(pool.stats()["phases"]["decode_wait"])
 """
 
 from __future__ import annotations
@@ -92,6 +100,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.pipeline.clock import WallClock
+from repro.pipeline.spans import Phases
 from repro.resilience import faults
 from repro.train.steps import make_serve_steps
 
@@ -148,7 +157,11 @@ class Request:
     error: FailReason | None = None
     error_detail: str | None = None
     slot: int | None = None
-    submitted_at: float = 0.0      # pool clock.now() at submit
+    # pool clock.now() at submit, when admission began (it left the
+    # queue), and when its first token was appended
+    submitted_at: float = 0.0
+    admitted_at: float | None = None
+    first_token_at: float | None = None
     admit_denials: int = 0         # backpressure retries so far
     pages_reserved: int = 0        # worst-case pages held while admitted
 
@@ -306,8 +319,8 @@ class ServePool:
         self._failures: collections.deque[dict] = collections.deque(
             maxlen=self._failure_cap)
         self._fail_reasons: collections.Counter = collections.Counter()
-        self._decode_seconds = 0.0
-        self._admit_seconds = 0.0
+        # host time of each phase of step(), and its profiler spans
+        self._phases = Phases("pool")
 
     # ---- admission ----
 
@@ -524,14 +537,18 @@ class ServePool:
         """Prefill the prompt at batch 1 and scatter its cache rows into
         ``slot``.  The prefill's last-position logits yield the tenant's
         FIRST generated token (mirror of ``ServeHandle.generate``)."""
-        t0 = time.perf_counter()
         req.slot = slot
+        req.admitted_at = self.clock.now()
         batch = {"tokens": jnp.asarray(req.prompt)[None, :]}
         self._prefill_shapes.add(int(req.prompt.size))
-        logits, cache1 = self._prefill1(self._sparams, batch,
-                                        self._cache1_template)
-        first = int(np.asarray(jnp.argmax(logits[:, -1], -1))[0])
+        with self._phases("prefill_chunk", rid=req.rid, chunk=0,
+                          tokens=int(req.prompt.size)):
+            logits, cache1 = self._prefill1(self._sparams, batch,
+                                            self._cache1_template)
+        with self._phases("first_token", rid=req.rid):
+            first = int(np.asarray(jnp.argmax(logits[:, -1], -1))[0])
         req.tokens.append(first)
+        req.first_token_at = self.clock.now()
         self._tokens_generated += 1
         self._prefill_tokens += int(req.prompt.size)
         if req.max_new_tokens == 1 or first == req.eos_id:
@@ -540,9 +557,9 @@ class ServePool:
             req.status = "live"
             self._slot_rid[slot] = req.rid
             self._last_tok[slot, 0] = first
-            self._cache = self._adopt(self._cache, cache1,
-                                      jnp.int32(slot))
-        self._admit_seconds += time.perf_counter() - t0
+            with self._phases("adopt", rid=req.rid, slot=slot):
+                self._cache = self._adopt(self._cache, cache1,
+                                          jnp.int32(slot))
 
     # ---- continuous admission (chunked / length-bucketed prefill) ----
     #
@@ -582,6 +599,7 @@ class ServePool:
         """Begin a (possibly multi-step) chunked admission into ``slot``."""
         req.slot = slot
         req.status = "admitting"
+        req.admitted_at = self.clock.now()
         self._admit_state = {"req": req, "slot": slot,
                              "cache": self._cache1_template,
                              "pieces": self._pieces(req.prompt),
@@ -602,22 +620,23 @@ class ServePool:
                        f"deadline ({req.deadline_s}s) expired between "
                        f"prefill chunks ({st['next']}/{len(st['pieces'])})")
             return
-        t0 = time.perf_counter()
         piece = st["pieces"][st["next"]]
         self._prefill_shapes.add(int(piece.size))
-        logits, st["cache"] = self._chunk1(
-            self._sparams, {"tokens": jnp.asarray(piece)[None, :]},
-            st["cache"])
+        with self._phases("prefill_chunk", rid=req.rid, chunk=st["next"],
+                          tokens=int(piece.size)):
+            logits, st["cache"] = self._chunk1(
+                self._sparams, {"tokens": jnp.asarray(piece)[None, :]},
+                st["cache"])
         # the REAL last prompt token's logits row picks the first generated
         # token — under bucket padding that row is inside some chunk, not
         # necessarily the last position of the last chunk
         last = int(req.prompt.size) - 1
         if st["off"] <= last < st["off"] + piece.size:
-            st["first"] = int(np.asarray(
-                jnp.argmax(logits[0, last - st["off"]], -1)))
+            with self._phases("first_token", rid=req.rid):
+                st["first"] = int(np.asarray(
+                    jnp.argmax(logits[0, last - st["off"]], -1)))
         st["off"] += int(piece.size)
         st["next"] += 1
-        self._admit_seconds += time.perf_counter() - t0
         if st["next"] >= len(st["pieces"]):
             self._admit_state = None
             self._admit_complete(req, st)
@@ -625,9 +644,9 @@ class ServePool:
     def _admit_complete(self, req: Request, st: dict):
         """All chunks prefilled: emit the first token; adopt into the pool
         slot unless the request finished instantly (mirrors _admit_one)."""
-        t0 = time.perf_counter()
         first = st["first"]
         req.tokens.append(first)
+        req.first_token_at = self.clock.now()
         self._tokens_generated += 1
         self._prefill_tokens += int(req.prompt.size)
         if req.max_new_tokens == 1 or first == req.eos_id:
@@ -638,13 +657,15 @@ class ServePool:
             # context (paged: only ceil(real/ps) pages — padding pages
             # never reach the pool), and decode overwrites the padded KV
             # at position ``real_len`` before anything attends it
-            cache1 = self._fix_len(st["cache"], jnp.int32(req.prompt.size))
             slot = st["slot"]
+            with self._phases("adopt", rid=req.rid, slot=slot):
+                cache1 = self._fix_len(st["cache"],
+                                       jnp.int32(req.prompt.size))
+                self._cache = self._adopt(self._cache, cache1,
+                                          jnp.int32(slot))
             req.status = "live"
             self._slot_rid[slot] = req.rid
             self._last_tok[slot, 0] = first
-            self._cache = self._adopt(self._cache, cache1, jnp.int32(slot))
-        self._admit_seconds += time.perf_counter() - t0
 
     def _admission_blocked(self, req: Request) -> bool:
         """Page backpressure: deny admission while the head request's
@@ -770,52 +791,70 @@ class ServePool:
         went non-finite fails ALONE — no token is appended for it, its slot
         and pages are freed, and every healthy slot's argmax is taken from
         the same logit values it would see in a fault-free run (token
-        parity is asserted in tests/test_resilience.py)."""
-        self._expire()
-        self._admit()
-        if self.live == 0:
-            return 0
-        t0 = time.perf_counter()
-        tok, logits, self._cache = self._decode(self._sparams,
-                                                jnp.asarray(self._last_tok),
-                                                self._cache)
-        # chaos: NaN-poison one slot's logits at the chosen decode step
-        # (host-side copy — device values and healthy slots are untouched)
-        corrupted = faults.corrupt_decode_logits(logits, self._decode_steps)
-        if corrupted is not None:
-            finite = np.isfinite(corrupted).all(
-                axis=tuple(range(1, corrupted.ndim)))
-            tok_host = np.argmax(corrupted[:, -1], axis=-1
-                                 ).astype(np.int32)[:, None]
-        else:
-            finite = (np.asarray(self._finite(logits))
-                      if self.guard_logits else None)
-            tok_host = np.asarray(tok)
-        self._decode_seconds += time.perf_counter() - t0
-        self._decode_steps += 1
-        advanced = 0
-        for slot, rid in enumerate(self._slot_rid):
-            if rid is None:
-                continue
-            advanced += 1
-            req = self._requests[rid]
-            if finite is not None and not finite[slot]:
-                self._fail(req, FailReason.QUARANTINE,
-                           "non-finite logits at decode step "
-                           f"{self._decode_steps - 1} (slot {slot} "
-                           "quarantined)")
-                self._release_slot(slot)
-                continue            # no token appended for the bad slot
-            t = int(tok_host[slot, 0])
-            req.tokens.append(t)
-            self._tokens_generated += 1
-            self._decode_tokens += 1
-            self._last_tok[slot, 0] = t
-            if len(req.tokens) >= req.max_new_tokens or t == req.eos_id:
-                self._finish(req)
-                self._release_slot(slot)  # recycled at next admission
-        self._live_slot_steps += advanced
-        return advanced
+        parity is asserted in tests/test_resilience.py).
+
+        Every phase below is a span ``pool.<phase>`` nested in
+        ``pool.step`` and a counter in ``stats()["phases"]``;
+        ``first_token`` and ``decode_wait`` are the host's waits on the
+        device."""
+        phase = self._phases
+        with phase("step", live=self.live, pending=self.pending):
+            with phase("expire"):
+                self._expire()
+            # the request admission serves: the in-flight one, else the head
+            st = self._admit_state
+            head = (st["req"].rid if st is not None
+                    else self._queue[0] if self._queue else None)
+            with phase("admit", **({} if head is None else {"rid": head})):
+                self._admit()
+            if self.live == 0:
+                return 0
+            with phase("decode"):
+                tok, logits, self._cache = self._decode(
+                    self._sparams, jnp.asarray(self._last_tok), self._cache)
+            with phase("decode_wait"):
+                # chaos: NaN-poison one slot's logits at the chosen decode
+                # step (host-side copy — device values and healthy slots
+                # are untouched)
+                corrupted = faults.corrupt_decode_logits(logits,
+                                                         self._decode_steps)
+                if corrupted is not None:
+                    finite = np.isfinite(corrupted).all(
+                        axis=tuple(range(1, corrupted.ndim)))
+                    tok_host = np.argmax(corrupted[:, -1], axis=-1
+                                         ).astype(np.int32)[:, None]
+                else:
+                    finite = (np.asarray(self._finite(logits))
+                              if self.guard_logits else None)
+                    tok_host = np.asarray(tok)
+            self._decode_steps += 1
+            advanced = finished = 0
+            with phase("emit") as span:
+                for slot, rid in enumerate(self._slot_rid):
+                    if rid is None:
+                        continue
+                    advanced += 1
+                    req = self._requests[rid]
+                    if finite is not None and not finite[slot]:
+                        self._fail(req, FailReason.QUARANTINE,
+                                   "non-finite logits at decode step "
+                                   f"{self._decode_steps - 1} (slot {slot} "
+                                   "quarantined)")
+                        self._release_slot(slot)
+                        continue            # no token appended for it
+                    t = int(tok_host[slot, 0])
+                    req.tokens.append(t)
+                    self._tokens_generated += 1
+                    self._decode_tokens += 1
+                    self._last_tok[slot, 0] = t
+                    if (len(req.tokens) >= req.max_new_tokens
+                            or t == req.eos_id):
+                        self._finish(req)
+                        self._release_slot(slot)  # recycled at next admission
+                        finished += 1
+                span.set_metadata(finished=finished)
+            self._live_slot_steps += advanced
+            return advanced
 
     def run(self, budget_s: float | None = None) -> dict[int, np.ndarray]:
         """Drain the pool: step until every submitted request completed (or
@@ -863,9 +902,10 @@ class ServePool:
 
     def stats(self) -> dict:
         """Scheduler counters: slot occupancy (mean live fraction per decode
-        step), aggregate tokens/s (prefill-admissions included in the
-        denominator), and admission/completion totals."""
-        busy = self._decode_seconds + self._admit_seconds
+        step), token and admission/completion totals, and ``phases``: for
+        each phase of ``step()``, ``{"n", "s", "max_s"}``: entries and host
+        seconds (``time.perf_counter()``) since the pool was built, and the
+        longest single entry since the previous ``stats()`` call."""
         page_pool = None
         if self.paged:
             pages = int(self._cache["k_pages"].shape[1])
@@ -895,22 +935,12 @@ class ServePool:
             "tokens_generated": self._tokens_generated,
             "occupancy": (self._live_slot_steps
                           / max(self._decode_steps * self.slots, 1)),
-            "decode_seconds": round(self._decode_seconds, 4),
-            "admit_seconds": round(self._admit_seconds, 4),
             "init_seconds": round(self.init_seconds, 4),
-            "tok_per_s": round(self._tokens_generated / busy, 1)
-            if busy > 0 else 0.0,
-            # phase-split throughput: prefill counts REAL prompt tokens
-            # (bucket padding excluded) over admission wall time; decode
-            # counts batched-decode tokens over decode wall time
+            # REAL prompt tokens prefilled (bucket padding excluded), and
+            # tokens produced by batched decode
             "prefill_tokens": self._prefill_tokens,
             "decode_tokens": self._decode_tokens,
-            "prefill_toks_s": round(
-                self._prefill_tokens / self._admit_seconds, 1)
-            if self._admit_seconds > 0 else 0.0,
-            "decode_toks_s": round(
-                self._decode_tokens / self._decode_seconds, 1)
-            if self._decode_seconds > 0 else 0.0,
+            "phases": self._phases.snapshot(),
             # admission retrace accounting: distinct prefill/chunk sequence
             # lengths fed to the batch-1 jit (each is one trace); bucketing
             # bounds this at ~log2(max_len)

@@ -143,8 +143,12 @@ def test_session_report_surfaces_pool_stats(session):
     rep = session.report()
     assert "serve_pools" in rep and len(rep["serve_pools"]) >= 1
     st = rep["serve_pools"][-1]
-    assert {"slots", "occupancy", "tok_per_s", "completed"} <= set(st)
+    assert {"slots", "occupancy", "phases", "completed"} <= set(st)
     assert st["completed"] == 1
+    # one admission prefill token + one decoded token; every decode step
+    # waited on the device once
+    assert st["tokens_generated"] == 2 and st["decode_tokens"] == 1
+    assert st["phases"]["decode_wait"]["n"] == st["decode_steps"]
     n_live = len(rep["serve_pools"])
     del pool, st, rep
     gc.collect()
@@ -166,3 +170,47 @@ def test_pool_incremental_stepping_and_late_submit(session, serial_handle):
                                   _serial(serial_handle, prompts[0], 4))
     np.testing.assert_array_equal(outs[r2],
                                   _serial(serial_handle, prompts[1], 3))
+
+
+def test_pool_phase_counters_count_each_step(session):
+    """stats()["phases"] counts one ``step`` per step() call; the longest
+    entry never exceeds the total and restarts at each stats() call."""
+    pool = session.serve_pool(slots=2, max_len=MAX_LEN)
+    for p in _prompts((5, 8, 6), seed=11):
+        pool.submit(p, max_new_tokens=3)
+    calls = 0
+    while pool.live or pool.pending:
+        pool.step()
+        calls += 1
+    ph = pool.stats()["phases"]
+    assert ph["step"]["n"] == calls
+    assert ph["decode_wait"]["n"] == ph["emit"]["n"] == (
+        pool.stats()["decode_steps"])
+    assert ph["first_token"]["n"] == ph["prefill_chunk"]["n"] == 3
+    assert all(0 <= p["max_s"] <= p["s"] for p in ph.values())
+    again = pool.stats()["phases"]
+    assert all(again[k]["max_s"] == 0.0 and again[k]["n"] == ph[k]["n"]
+               for k in ph)
+
+
+@pytest.mark.parametrize("kw", [{}, {"prefill_chunk": 4,
+                                    "bucket_prompts": True}],
+                         ids=["whole-prompt", "chunked"])
+def test_request_stamps_ordered_on_virtual_clock(session, kw):
+    """admitted_at (left the queue) and first_token_at (first token
+    appended) are stamped on the pool's clock, after submitted_at."""
+    from repro.pipeline.clock import VirtualClock
+    pool = session.serve_pool(slots=2, max_len=MAX_LEN,
+                              clock=VirtualClock(step_s=0.01), **kw)
+    rids = [pool.submit(p, max_new_tokens=3)
+            for p in _prompts((11, 9, 13), seed=12)]
+    pool.run()
+    reqs = [pool.request(r) for r in rids]
+    for r in reqs:
+        assert r.submitted_at <= r.admitted_at <= r.first_token_at
+    # two slots: the third request waited in the queue
+    assert reqs[2].admitted_at > reqs[2].submitted_at
+    if kw:
+        # chunked: an admission beside a live tenant takes one chunk a
+        # step, so its first token lands steps after admission began
+        assert reqs[1].first_token_at > reqs[1].admitted_at

@@ -142,8 +142,13 @@ def test_traffic_stress_parity_and_page_accounting(kw):
     if st["page_pool"] is not None:
         assert st["page_pool"]["used"] == 0, "leaked pages after drain"
         assert st["page_pool"]["reserved"] == 0, "leaked reservations"
-    # phase-split throughput surfaced (satellite: tok/s split)
-    assert st["prefill_toks_s"] > 0 and st["decode_toks_s"] > 0
+    # host time per phase: one first-token read per request, at least one
+    # prefill chunk each, one decode wait per decode step
+    ph = st["phases"]
+    assert ph["first_token"]["n"] == N_PER_CASE
+    assert ph["prefill_chunk"]["n"] >= N_PER_CASE
+    assert ph["decode_wait"]["n"] == st["decode_steps"]
+    assert all(0 <= p["max_s"] <= p["s"] and p["s"] > 0 for p in ph.values())
     assert st["prefill_tokens"] == sum(r.prompt.size for r in trace)
     # each request's FIRST token comes from the admission prefill, the
     # rest from batched decode
