@@ -223,16 +223,21 @@ def _flash_jit(q, k_pages, v_pages, page_table, lengths, bias,
 # --------------------------------------------------------------------------
 
 
-def gather_pages(pages, page_table):
+def gather_pages(pages, page_table, layer_idx=None):
     """(P, ps, KV, Dh) pages + (B, MP) table -> contiguous (B, MP*ps, KV, Dh).
+
+    With ``layer_idx``, ``pages`` is the whole layer stack (L, P, ps, KV,
+    Dh) and the layer index folds into the same gather: no slice of the
+    layer's pool is materialized first.
 
     Unmapped (-1) entries are clamped to page 0 — their positions are past
     every slot's length, so the caller's mask zeroes them exactly and token
     parity with the dense-cache path is preserved."""
     b, mp = page_table.shape
-    _, ps, kv, dh = pages.shape
-    out = pages[jnp.maximum(page_table, 0)]        # (B, MP, ps, KV, Dh)
-    return out.reshape(b, mp * ps, kv, dh)
+    ps, kv, dh = pages.shape[-3:]
+    idx = jnp.maximum(page_table, 0)
+    out = pages[idx] if layer_idx is None else pages[layer_idx, idx]
+    return out.reshape(b, mp * ps, kv, dh)         # out: (B, MP, ps, KV, Dh)
 
 
 # --------------------------------------------------------------------------

@@ -151,16 +151,20 @@ def causal_mask(sq: int, sk: int, *, window: int | None = None,
     return m[None, None]
 
 
-def _paged_prefill_append(cache, k, v):
+def _paged_prefill_append(cache, k, v, lead=()):
     """Write a start-0 prompt's K/V into freshly allocated pages.
 
     Prefill always begins at position 0 (its masks/positions assume it), so
     allocation is a vectorized pop of ``ceil(s / ps)`` pages per slot off
-    the free-list stack.  Returns the updated paged-cache leaves."""
+    the free-list stack.  Returns the updated paged-cache leaves.
+
+    ``lead`` indexes the page pools ahead of the page dim: ``(layer,)``
+    when they are the whole layer stack (written in place, see
+    ``apply_attention``), ``()`` for one layer's pool."""
     b, s = k.shape[0], k.shape[1]
     kp, vp = cache["k_pages"], cache["v_pages"]
     table, fl, fc = cache["page_table"], cache["free_list"], cache["free_count"]
-    ps = kp.shape[1]
+    ps = kp.shape[-3]
     npg = -(-s // ps)                              # pages per slot (static)
     pad = npg * ps - s
     kq = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(kp.dtype)
@@ -168,14 +172,15 @@ def _paged_prefill_append(cache, k, v):
     kq = kq.reshape(b, npg, ps, *k.shape[2:])
     vq = vq.reshape(b, npg, ps, *v.shape[2:])
     pids = fl[fc - 1 - jnp.arange(b * npg)].reshape(b, npg)
-    kp = kp.at[pids.reshape(-1)].set(kq.reshape(b * npg, ps, *k.shape[2:]))
-    vp = vp.at[pids.reshape(-1)].set(vq.reshape(b * npg, ps, *v.shape[2:]))
+    at = (*lead, pids.reshape(-1))
+    kp = kp.at[at].set(kq.reshape(b * npg, ps, *k.shape[2:]))
+    vp = vp.at[at].set(vq.reshape(b * npg, ps, *v.shape[2:]))
     table = table.at[:, :npg].set(pids)
     return dict(cache, k_pages=kp, v_pages=vp, page_table=table,
                 free_count=fc - b * npg, pos=cache["pos"] + s)
 
 
-def _paged_chunk_append(cache, k, v):
+def _paged_chunk_append(cache, k, v, lead=()):
     """Append an ``s``-token prefill CHUNK at each slot's current position,
     allocating pages lazily for every page boundary the chunk crosses.
 
@@ -189,7 +194,7 @@ def _paged_chunk_append(cache, k, v):
     kp, vp = cache["k_pages"], cache["v_pages"]
     table, fl, fc = cache["page_table"], cache["free_list"], cache["free_count"]
     pos = cache["pos"]                             # (B,)
-    p_total, ps = kp.shape[0], kp.shape[1]
+    p_total, ps = kp.shape[-4], kp.shape[-3]
     mp = table.shape[1]
     # map every logical page the chunk touches that has no physical page yet
     pages = jnp.arange(mp)[None, :]                # (1, MP)
@@ -207,16 +212,16 @@ def _paged_chunk_append(cache, k, v):
     phys = jnp.take_along_axis(table, lp, axis=1)  # (B, s)
     phys_w = jnp.where(oob, p_total, phys).reshape(-1)
     off_w = jnp.where(oob, ps, g % ps).reshape(-1)
-    kp = kp.at[phys_w, off_w].set(
+    kp = kp.at[(*lead, phys_w, off_w)].set(
         k.reshape(b * s, *k.shape[2:]).astype(kp.dtype))
-    vp = vp.at[phys_w, off_w].set(
+    vp = vp.at[(*lead, phys_w, off_w)].set(
         v.reshape(b * s, *v.shape[2:]).astype(vp.dtype))
     return dict(cache, k_pages=kp, v_pages=vp, page_table=table,
                 free_count=fc - jnp.sum(flat.astype(jnp.int32)),
                 pos=pos + s)
 
 
-def _paged_decode_append(cache, k, v):
+def _paged_decode_append(cache, k, v, lead=()):
     """Append one (KV, Dh) row per slot at its own position, allocating a
     fresh page lazily when a slot crosses a page boundary.
 
@@ -227,7 +232,7 @@ def _paged_decode_append(cache, k, v):
     kp, vp = cache["k_pages"], cache["v_pages"]
     table, fl, fc = cache["page_table"], cache["free_list"], cache["free_count"]
     pos = cache["pos"]                             # (B,)
-    p_total, ps = kp.shape[0], kp.shape[1]
+    p_total, ps = kp.shape[-4], kp.shape[-3]
     mp = table.shape[1]
     oob = pos >= mp * ps
     lp = jnp.minimum(pos // ps, mp - 1)            # logical page (clamped)
@@ -243,15 +248,15 @@ def _paged_decode_append(cache, k, v):
     phys = table[rows, lp]                         # (B,) now mapped
     phys_w = jnp.where(oob, p_total, phys)         # dropped when oob
     off_w = jnp.where(oob, ps, off)
-    kp = kp.at[phys_w, off_w].set(k[:, 0].astype(kp.dtype))
-    vp = vp.at[phys_w, off_w].set(v[:, 0].astype(vp.dtype))
+    kp = kp.at[(*lead, phys_w, off_w)].set(k[:, 0].astype(kp.dtype))
+    vp = vp.at[(*lead, phys_w, off_w)].set(v[:, 0].astype(vp.dtype))
     return dict(cache, k_pages=kp, v_pages=vp, page_table=table,
                 free_count=fc - jnp.sum(need.astype(jnp.int32)),
                 pos=pos + 1)
 
 
 def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
-                     mask, phase: str, chunk: bool = False):
+                     mask, phase: str, chunk: bool = False, layer_idx=None):
     """Self-attention over a paged KV cache (see ``transformer.init_cache``
     ``paged=True``).  Prefill attends over the in-hand prompt K/V; decode
     appends one row per slot and dispatches to the flash kernel or the
@@ -261,24 +266,30 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
     (nonzero) position: the chunk is appended via ``_paged_chunk_append``
     and its queries attend the whole mapped span (earlier chunks included)
     through the ``gather_pages`` contiguous view, masked by the caller's
-    offset-aware mask — token-identical to an unchunked prefill."""
+    offset-aware mask — token-identical to an unchunked prefill.
+
+    ``layer_idx`` (see ``apply_attention``): the page pools are the whole
+    layer stack, written and read at that layer."""
     from repro.kernels import decode_attention as DA
     from repro.parallel.ctx import get_mesh, shard_dims
     b, s = q.shape[0], q.shape[1]
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kvh
+    lead = () if layer_idx is None else (layer_idx,)
     if s > 1 and chunk:                            # prefill chunk (start >= 0)
-        new_cache = _paged_chunk_append(cache, k, v)
-        kc = DA.gather_pages(new_cache["k_pages"], new_cache["page_table"])
-        vc = DA.gather_pages(new_cache["v_pages"], new_cache["page_table"])
+        new_cache = _paged_chunk_append(cache, k, v, lead)
+        kc = DA.gather_pages(new_cache["k_pages"], new_cache["page_table"],
+                             layer_idx)
+        vc = DA.gather_pages(new_cache["v_pages"], new_cache["page_table"],
+                             layer_idx)
         w = attention_scores(q, kc, cfg, mask)
         y = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(vc.dtype), vc)
     elif s > 1:                                    # prefill (start == 0)
-        new_cache = _paged_prefill_append(cache, k, v)
+        new_cache = _paged_prefill_append(cache, k, v, lead)
         w = attention_scores(q, k, cfg, mask[..., :s])
         y = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(v.dtype), v)
     else:                                          # single-token decode
-        new_cache = _paged_decode_append(cache, k, v)
+        new_cache = _paged_decode_append(cache, k, v, lead)
         kp, vp = new_cache["k_pages"], new_cache["v_pages"]
         # pin the paged layout (``sharding.cache_sharding``) so GSPMD never
         # reshards the pool per layer: KV heads over model where the axis
@@ -287,11 +298,12 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
         mesh = get_mesh()
         model = dict(zip(mesh.axis_names, mesh.devices.shape)).get(
             "model", 1) if mesh is not None else 1
-        pin = {2: "model"} if kvh % model == 0 else {1: "model"}
+        pin = ({len(lead) + 2: "model"} if kvh % model == 0
+               else {len(lead) + 1: "model"})
         kp, vp = shard_dims(kp, pin), shard_dims(vp, pin)
         new_cache = dict(new_cache, k_pages=kp, v_pages=vp)
         table = new_cache["page_table"]
-        ps, mp = kp.shape[1], table.shape[1]
+        ps, mp = kp.shape[-3], table.shape[1]
         # the kernel runs per device over its own KV heads (a Mosaic kernel
         # is never partitioned automatically); with heads the model axis
         # does not divide, the XLA gather is the only path
@@ -312,8 +324,9 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
                                                 P(), P()),
                     out_specs=heads, check_vma=False)
             try:
-                y = flash(q[:, 0].reshape(b, kvh, g, dh), kp, vp, table,
-                          lengths, bias)
+                # the kernel takes one layer's pool: a read-only slice
+                y = flash(q[:, 0].reshape(b, kvh, g, dh), kp[lead], vp[lead],
+                          table, lengths, bias)
                 y = y[:, None]                     # (B, 1, KV, G, Dh)
             except Exception as e:                 # noqa: BLE001
                 # Pallas failures surface at trace/lowering time; degrade
@@ -323,8 +336,8 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
                 DA.note_fallback(e)
                 y = None
         if y is None:
-            kc = DA.gather_pages(kp, table)
-            vc = DA.gather_pages(vp, table)
+            kc = DA.gather_pages(kp, table, layer_idx)
+            vc = DA.gather_pages(vp, table, layer_idx)
             w = attention_scores(q, kc, cfg, mask)
             y = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(vc.dtype), vc)
     y = y.reshape(b, s, h * dh)
@@ -333,7 +346,8 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
 
 def apply_attention(params, x, cfg: AttnCfg, mpo: MPOConfig, *,
                     positions, mask, kv_x=None, cache=None,
-                    phase: str = "train", chunk: bool = False):
+                    phase: str = "train", chunk: bool = False,
+                    layer_idx=None):
     """Returns (y, new_cache).
 
     ``cache``: dict(k, v, pos) for incremental decode — or the paged form
@@ -346,7 +360,14 @@ def apply_attention(params, x, cfg: AttnCfg, mpo: MPOConfig, *,
     a multi-token prefill CHUNK continuing at the cache's current position
     (``transformer.prefill_chunk``): the caller supplies offset-aware
     positions/mask; the dense cache path already appends at ``pos`` for
-    multi-token writes, the paged path switches to the chunked append."""
+    multi-token writes, the paged path switches to the chunked append.
+
+    ``layer_idx`` (self-attention caches only): the cache's K/V buffers
+    (``k``/``v`` or ``k_pages``/``v_pages``) are the WHOLE layer stack,
+    and this layer appends at index ``layer_idx`` in one scatter and reads
+    its own slice back — the layer scan carries the stack and updates it in
+    place instead of slicing each layer out and stacking it back
+    (``transformer._run_stack``).  The returned cache holds the stacks."""
     b = x.shape[0]
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = _split_heads(L.apply_linear(params["wq"], x, cfg=mpo, phase=phase),
@@ -371,11 +392,12 @@ def apply_attention(params, x, cfg: AttnCfg, mpo: MPOConfig, *,
         v = gather_seq(v)
     if cache is not None and kv_x is None and "k_pages" in cache:
         return _paged_attention(params, q, k, v, cache, cfg, mpo, mask,
-                                phase, chunk=chunk)
+                                phase, chunk=chunk, layer_idx=layer_idx)
     new_cache = None
     if cache is not None:
         if kv_x is None:  # self-attention decode: append to ring buffer
             from repro.parallel.ctx import shard_dims  # lazy: avoid cycle
+            lead = () if layer_idx is None else (layer_idx,)
             idx = cache["pos"]
             per_slot = getattr(idx, "ndim", 0) >= 1
             if per_slot and x.shape[1] == 1:
@@ -384,25 +406,28 @@ def apply_attention(params, x, cfg: AttnCfg, mpo: MPOConfig, *,
                 # slot.  Out-of-bounds writes (an idle slot past max_len)
                 # are dropped by the scatter, never clobber a live tenant.
                 rows = jnp.arange(b)
-                kc = cache["k"].at[rows, idx].set(
+                kc = cache["k"].at[(*lead, rows, idx)].set(
                     k[:, 0].astype(cache["k"].dtype))
-                vc = cache["v"].at[rows, idx].set(
+                vc = cache["v"].at[(*lead, rows, idx)].set(
                     v[:, 0].astype(cache["v"].dtype))
             else:
                 # prefill (all rows start at the same offset) or a legacy
                 # scalar-pos cache: one contiguous slice write
-                start = idx[0] if per_slot else idx
+                start = (*lead, 0, idx[0] if per_slot else idx, 0, 0)
+                one = (1,) * len(lead)
                 kc = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, start, 0, 0))
+                    cache["k"], k.astype(cache["k"].dtype).reshape(
+                        one + k.shape), start)
                 vc = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, start, 0, 0))
+                    cache["v"], v.astype(cache["v"].dtype).reshape(
+                        one + v.shape), start)
             # pin the flash-decoding layout: cache seq dim model-sharded,
             # batch data-sharded (GSPMD otherwise reshards the whole cache
             # to kv-head sharding per layer — §Perf it.10)
-            spec = {0: "batch", 1: "model"}
+            spec = {len(lead): "batch", len(lead) + 1: "model"}
             kc = shard_dims(kc, spec)
             vc = shard_dims(vc, spec)
-            k, v = kc, vc
+            k, v = kc[lead], vc[lead]
             new_cache = {"k": kc, "v": vc, "pos": idx + x.shape[1]}
         else:  # cross-attention: cache holds precomputed enc k/v
             k, v = cache["k"], cache["v"]

@@ -83,7 +83,7 @@ def _is_local_flags(cfg: ModelConfig) -> jax.Array:
 
 
 def _layer_fwd(cfg: ModelConfig, x, layer, *, positions, mask, mask_local,
-               cache=None, phase="train", chunk=False):
+               cache=None, phase="train", chunk=False, layer_idx=None):
     acfg = attn_cfg(cfg)
     is_local = layer.pop("_is_local") if "_is_local" in layer else None
     m = mask if is_local is None else jnp.where(is_local, mask_local, mask)
@@ -91,7 +91,8 @@ def _layer_fwd(cfg: ModelConfig, x, layer, *, positions, mask, mask_local,
     h = nn.apply_rmsnorm(layer["ln1"], x)
     a, new_cache = nn.apply_attention(layer["attn"], h, acfg, cfg.mpo,
                                       positions=positions, mask=m, cache=cache,
-                                      phase=phase, chunk=chunk)
+                                      phase=phase, chunk=chunk,
+                                      layer_idx=layer_idx)
     x = ctx.shard_activation(x + a)
     h = nn.apply_rmsnorm(layer["ln2"], x)
     if cfg.num_experts:
@@ -104,21 +105,42 @@ def _layer_fwd(cfg: ModelConfig, x, layer, *, positions, mask, mask_local,
     return ctx.shard_activation(x + f), new_cache, aux
 
 
+# the cache leaves that hold K/V (dense or paged): the large ones
+_KV_LEAVES = ("k", "v", "k_pages", "v_pages")
+
+
 def _run_stack(cfg: ModelConfig, params, x, *, positions, mask, mask_local,
                caches=None, phase="train", chunk=False):
-    """Scan the layer stack; returns (x, new_caches, aux_loss_sum)."""
+    """Scan the layer stack; returns (x, new_caches, aux_loss_sum).
+
+    With ``caches``, the K/V leaves ride in the scan CARRY as whole layer
+    stacks, with the layer index scanned alongside: each layer appends
+    into its own slice of the stack in place and reads it back, so no
+    layer's K/V is sliced out of the stack and stacked back each step.
+    The small bookkeeping leaves (positions, page tables, free lists) are
+    scanned per layer as before."""
     flags = _is_local_flags(cfg)
+    kv, rest, idx = {}, None, None
+    if caches is not None:
+        kv = {n: caches[n] for n in _KV_LEAVES if n in caches}
+        rest = {n: a for n, a in caches.items() if n not in kv}
+        idx = jnp.arange(cfg.num_layers)
 
     def body(carry, scanned):
-        x, aux_sum = carry
-        layer, flag, cache = scanned
+        x, aux_sum, kv = carry
+        layer, flag, cache, i = scanned
         layer = dict(layer)
         if cfg.local_window is not None:
             layer["_is_local"] = flag
+        if cache is not None:
+            cache = dict(cache, **kv)
         y, new_cache, aux = _layer_fwd(cfg, x, layer, positions=positions,
                                        mask=mask, mask_local=mask_local,
-                                       cache=cache, phase=phase, chunk=chunk)
-        return (y, aux_sum + aux), new_cache
+                                       cache=cache, phase=phase, chunk=chunk,
+                                       layer_idx=i)
+        if new_cache is not None:
+            kv = {n: new_cache.pop(n) for n in kv}
+        return (y, aux_sum + aux, kv), new_cache
 
     if cfg.remat:
         body = jax.checkpoint(
@@ -129,9 +151,11 @@ def _run_stack(cfg: ModelConfig, params, x, *, positions, mask, mask_local,
         layer_params = jax.tree.map(
             lambda a: jnp.broadcast_to(a[0], (cfg.num_layers,) + a.shape[1:]),
             layer_params)
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.array(0.0, jnp.float32)),
-        (layer_params, flags, caches))
+    (x, aux, kv), new_caches = jax.lax.scan(
+        body, (x, jnp.array(0.0, jnp.float32), kv),
+        (layer_params, flags, rest, idx))
+    if caches is not None:
+        new_caches = dict(new_caches, **kv)
     return x, new_caches, aux
 
 

@@ -17,6 +17,13 @@ each row at its OWN offset.  ``ServePool`` is the scheduler on top:
   host time of each phase of ``step()`` (``"phases"``) —
   ``Session.report()`` surfaces it for every pool the session created.
 
+The pool cache is DONATED to every program that returns its successor
+(decode, adoption, slot release, parking), so each writes the KV stack in
+place: the pool rebinds ``_cache`` to the result and never touches the old
+one.  ``stats()["kv_in_place"]`` says whether the K stack still lives in
+the device buffer the pool started with.  The batch-1 template that every
+admission starts from is never donated.
+
 Each phase of ``step()`` is a profiler span ``pool.<phase>`` on the host
 timeline, nested in ``pool.step`` and on the clock the device trace uses,
 and an always-on counter (count, total and longest seconds) under
@@ -242,7 +249,8 @@ class ServePool:
             # park every slot at the capacity sentinel: idle rows neither
             # write pages nor allocate from the shared pool until a tenant
             # is adopted into them
-            self._cache = jax.jit(self._park_all)(self._cache)
+            self._cache = jax.jit(self._park_all, donate_argnums=0)(
+                self._cache)
         # Admission path: batch-1 prefill over the SAME weight snapshot —
         # serve params are batch-independent, so the pool never contracts
         # (or, under a mesh, places) a second copy of the weights.  Only a
@@ -252,7 +260,7 @@ class ServePool:
         # it without explicit in_shardings.
         cache_kw = {"paged": True, "page_size": page_size} if paged else {}
         if mesh is None:
-            self._decode = jax.jit(self._decode)
+            self._decode = jax.jit(self._decode, donate_argnums=2)
             self._prefill1 = jax.jit(prefill)
             self._chunk1 = (jax.jit(chunk_step)
                             if chunk_step is not None else None)
@@ -283,8 +291,10 @@ class ServePool:
         self.init_seconds = time.perf_counter() - t0
 
         self._adopt = jax.jit(self._adopt_paged_fn if paged
-                              else self._adopt_fn)
-        self._free = jax.jit(self._free_slot_fn) if paged else None
+                              else self._adopt_fn, donate_argnums=0)
+        self._free = (jax.jit(self._free_slot_fn, donate_argnums=0)
+                      if paged else None)
+        self._kv_start = self._kv_buffers()
         # per-slot finiteness of the decode logits (device-side reduce: a
         # (slots,) bool vector crosses to host, never the logits)
         self._finite = jax.jit(
@@ -900,12 +910,24 @@ class ServePool:
 
     # ---- reporting ----
 
+    def _kv_buffers(self) -> tuple | None:
+        """Device buffers of the pool's K stack, one per addressable shard
+        (``None`` for a cache without K/V)."""
+        if not isinstance(self._cache, dict):
+            return None
+        k = self._cache.get("k_pages", self._cache.get("k"))
+        return tuple(sh.data.unsafe_buffer_pointer()
+                     for sh in k.addressable_shards)
+
     def stats(self) -> dict:
         """Scheduler counters: slot occupancy (mean live fraction per decode
         step), token and admission/completion totals, and ``phases``: for
         each phase of ``step()``, ``{"n", "s", "max_s"}``: entries and host
         seconds (``time.perf_counter()``) since the pool was built, and the
-        longest single entry since the previous ``stats()`` call."""
+        longest single entry since the previous ``stats()`` call.
+        ``kv_in_place``: the K stack still sits in the device buffer it
+        was allocated in (every program that rebinds the cache wrote it in
+        place); ``None`` without a KV cache."""
         page_pool = None
         if self.paged:
             pages = int(self._cache["k_pages"].shape[1])
@@ -941,6 +963,8 @@ class ServePool:
             "prefill_tokens": self._prefill_tokens,
             "decode_tokens": self._decode_tokens,
             "phases": self._phases.snapshot(),
+            "kv_in_place": (None if self._kv_start is None
+                            else self._kv_buffers() == self._kv_start),
             # admission retrace accounting: distinct prefill/chunk sequence
             # lengths fed to the batch-1 jit (each is one trace); bucketing
             # bounds this at ~log2(max_len)

@@ -122,6 +122,10 @@ class ServeHandle:
         return logits
 
     def decode(self, tokens: jax.Array):
+        if self.cache is self._cache0:
+            # a donating (mesh-jitted) decode would consume the pristine
+            # cache ``reset()`` hands out again
+            self.cache = jax.tree.map(jnp.copy, self._cache0)
         tok, logits, self.cache = self._decode(self.params, tokens, self.cache)
         return tok, logits
 

@@ -175,7 +175,9 @@ def make_serve_steps(model: Model, *, weight_cache: bool = True,
       ``in_shardings``/``out_shardings``: params pinned to their layout,
       the KV cache to ``parallel.sharding.cache_sharding`` (batch over
       ``data``, cache seq dim over ``model`` — the flash-decoding layout),
-      prompt/token inputs and logits replicated.
+      prompt/token inputs and logits replicated;
+    * the mesh-jitted decode DONATES the cache it is given: the caller
+      rebinds its cache to the returned one and never reads the old one.
 
     Example::
 
@@ -251,9 +253,11 @@ def make_serve_steps(model: Model, *, weight_cache: bool = True,
         jitted["prefill"] = jax.jit(prefill_step,
                                     in_shardings=(pshard, repl, cshard),
                                     out_shardings=(repl, cshard))
+        # the cache is donated: callers rebind it to the step's result
         jitted["decode"] = jax.jit(decode_step,
                                    in_shardings=(pshard, repl, cshard),
-                                   out_shardings=(repl, repl, cshard))
+                                   out_shardings=(repl, repl, cshard),
+                                   donate_argnums=2)
         return serve_params, cache
 
     def prefill_sharded(params, batch, cache):
