@@ -132,7 +132,21 @@ def program_config(conf: dict):
     if cfg.vocab_size != conf["vocab_size"]:
         raise SystemExit(f"program pads vocab {conf['vocab_size']} to "
                          f"{cfg.vocab_size}; state the padded size")
+    if program_norm_eps(cfg) != conf["rms_norm_eps"]:
+        raise SystemExit(f"program RMSNorm epsilon {program_norm_eps(cfg)} "
+                         f"is not the file's {conf['rms_norm_eps']}")
     return cfg
+
+
+def program_norm_eps(cfg) -> float:
+    """The RMSNorm epsilon the program runs: its configuration's
+    ``norm_eps`` where it has one, else ``apply_rmsnorm``'s default (one
+    epsilon for every model)."""
+    import inspect
+
+    from repro.models import nn
+    default = inspect.signature(nn.apply_rmsnorm).parameters["eps"].default
+    return getattr(cfg, "norm_eps", default)
 
 
 def reference_config(conf: dict) -> dict:

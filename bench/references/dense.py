@@ -19,11 +19,12 @@ rotate-half RoPE (``theta ** (-2i / head_dim)``) and scale
 ``head_dim ** -0.5``; ``qk_norm`` applies an RMSNorm over each head's
 ``head_dim`` before RoPE.  The final RMSNorm feeds an untied head.
 
-``quant`` (for the lower-precision control) rounds to a narrower type,
-with one scale per tensor, wherever the served program keeps a value in
-its compute type: both inputs of every projection, K and V, and the
-residual stream after each block.  The straight-through form keeps the
-backward pass exact.
+``quant`` (for the lower-precision control) computes in float8, with one
+scale per tensor, as float8 training does: every projection takes e4m3
+inputs, and its two backward matmuls take the incoming gradient rounded
+to e5m2; K and V and the residual stream after each block are rounded to
+e4m3 where the program keeps them in its compute type (straight-through:
+the gradient passes them unrounded).
 """
 
 from __future__ import annotations
@@ -68,20 +69,37 @@ def embed_rows(cores: list, ids: jax.Array) -> jax.Array:
     return t[..., 0]
 
 
-def fp8(x: jax.Array) -> jax.Array:
-    """float8_e4m3 rounding with one scale per tensor; straight-through."""
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
-    q = (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+def fp8(x: jax.Array, dtype=jnp.float8_e4m3fn) -> jax.Array:
+    """float8 rounding with one scale per tensor; straight-through."""
+    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / float(jnp.finfo(dtype).max)
+    q = (x / s).astype(dtype).astype(jnp.float32) * s
     return x + jax.lax.stop_gradient(q - x)
 
+
+@jax.custom_vjp
+def fp8_matmul(x, w):
+    """``x @ w`` (2-D) in float8: e4m3 inputs; backward, e5m2 gradient."""
+    return fp8(x) @ fp8(w)
+
+
+def _fp8_matmul_fwd(x, w):
+    qx, qw = fp8(x), fp8(w)
+    return qx @ qw, (qx, qw)
+
+
+def _fp8_matmul_bwd(res, dy):
+    qx, qw = res
+    g = fp8(dy, jnp.float8_e5m2)
+    return g @ qw.T, qx.T @ g
+
+
+fp8_matmul.defvjp(_fp8_matmul_fwd, _fp8_matmul_bwd)
 
 QUANT = {None: None, "fp8": fp8}
 
 
 def _mm(x, w, quant):
-    if quant is not None:
-        x, w = quant(x), quant(w)
-    return x @ w
+    return x @ w if quant is None else fp8_matmul(x, w)
 
 
 def _q(x, quant):

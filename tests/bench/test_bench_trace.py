@@ -83,10 +83,34 @@ def test_mfu_train_counts_six_flops_per_weight_per_token_plus_attention():
                 window=(0, 1_000_000_000))
     obs = {"trace": tr, "conf": conf, "mix": {"batch": 4, "seq_len": 2048},
            "peaks": peaks.peaks_for("TPU v5 lite"), "counters": {}}
-    flops = (6 * counts.dense_weights(conf) * 8192
+    # LFA needs no dense weight gradient: 4 (not 6) per weight per token
+    flops = (4 * counts.dense_weights(conf) * 8192
              + 3 * 4 * 32 * 128 * 4 * (4 * 2048 * 2049 // 2))
     assert _metric("mfu.train")(obs) == pytest.approx(
         100 * flops / 0.5 / 197e12)
+    six = flops + 2 * counts.dense_weights(conf) * 8192
+    assert _metric("mfu.train")(obs) < 100 * six / 0.5 / 197e12
+
+
+def test_mfu_train_hand_count_at_the_tiny_size():
+    # 2 layers of d 64, 4 heads x 16, 2 KV heads, d_ff 128, vocab 512:
+    # q, o 64 x 64; k, v 64 x 32; gate, up, down 64 x 128; head 64 x 512
+    conf = {"hidden_size": 64, "num_attention_heads": 4,
+            "num_key_value_heads": 2, "head_dim": 16,
+            "intermediate_size": 128, "num_hidden_layers": 2,
+            "vocab_size": 512, "dtype": "float32"}
+    weights = 2 * (2 * 4096 + 2 * 2048 + 3 * 8192) + 32768
+    assert counts.dense_weights(conf) == weights == 106496
+    tokens, pairs = 2 * 32, 2 * (32 * 33 // 2)
+    flops = 4 * weights * tokens + 3 * (4 * 4 * 16 * pairs * 2)
+    assert flops == 27_262_976 + 1_622_016
+    tr = _trace([], [(0, 1_000_000, "jit_train_step(3)"),
+                     (2_000_000, 3_000_000, "jit_train_step(3)")],
+                window=(0, 4_000_000))
+    obs = {"trace": tr, "conf": conf, "mix": {"batch": 2, "seq_len": 32},
+           "peaks": {"bf16_flops": 1e12}, "counters": {}}
+    assert _metric("mfu.train")(obs) == pytest.approx(
+        100 * flops / 1e-3 / 1e12)
 
 
 def test_mfu_decode_takes_the_binding_bound():
