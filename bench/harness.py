@@ -6,6 +6,19 @@ A cell names a configuration (``configs/<config>.json``) and a traffic mix
 (``traffic/<mix>.json``); its correctness limits are in
 ``limits/<cell>.json`` and each per-layer metric's reader in
 ``metrics/<metric>.py``.  Nothing here is specific to one of them.
+
+A configuration file reaches the program and the reference whole.  Every
+key that is not in ``METADATA`` goes to the program's ``ModelConfig``: to
+the field that ``_FIELDS`` names where the published name differs, else to
+the field of the same name; a key with no such field stops the run with
+"program has no field for <key>", unless ``_FIXED`` gives the one value
+the program runs and the file states it.  So a program that learns an
+architecture adds its fields under the published names (``kv_lora_rank``,
+``moe_intermediate_size``, ...), and a file that states them then runs
+with no edit here.  The reference gets the same keys (``reference_config``)
+and, as ``published_<key>``, each one that ``published`` restates.  A
+layer cut to this chip's share of the experts is the one case where a
+published value reaches the program (``program_config``).
 """
 
 from __future__ import annotations
@@ -109,30 +122,100 @@ def use_checkout_program() -> None:
         raise SystemExit(f"repro imported from {where}, not from {src}")
 
 
-# configuration-file key -> program ModelConfig field
+# Keys of a configuration file that are no sizes of the model: its
+# provenance and the program's own settings (placed apart), and keys that
+# select nothing in the program.  Neither the program nor the reference
+# gets them as sizes.
+METADATA = frozenset({
+    "name", "source", "arch", "reference", "mpo", "dtype", "published",
+    "reduced", "assumed", "deployment",
+    "max_position_embeddings",   # positions come from the traffic
+    "model_type",                # the family label; ``arch`` selects it
+    "ep_size",                   # the source's expert-parallel degree at
+                                 # run time; the cut states experts held
+})
+
+# Keys the program has no field for because it runs one value only: a
+# file that states that value passes, any other stops the run by name.
+_FIXED = {"attention_bias": False, "num_nextn_predict_layers": 0,
+          "moe_layer_freq": 1}
+
+# published name -> program ModelConfig field, where the two differ (a
+# key not listed goes to the field of its own name)
 _FIELDS = {"hidden_size": "d_model", "num_attention_heads": "num_heads",
            "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
            "intermediate_size": "d_ff", "vocab_size": "vocab_size",
            "num_hidden_layers": "num_layers", "rope_theta": "rope_theta",
-           "qk_norm": "qk_norm"}
+           "qk_norm": "qk_norm", "hidden_act": "mlp_act",
+           "tie_word_embeddings": "tie_embeddings",
+           "num_experts": "num_experts", "n_routed_experts": "num_experts",
+           "num_local_experts": "num_experts",
+           "num_experts_per_tok": "top_k", "sliding_window": "local_window",
+           "rms_norm_eps": "norm_eps"}
+
+
+def expert_key(conf: dict) -> str | None:
+    """The file's key that counts the routed experts, if it states one."""
+    keys = [k for k in conf if _FIELDS.get(k) == "num_experts"]
+    if len(keys) > 1:
+        raise SystemExit(f"{' and '.join(keys)} both count the experts")
+    return keys[0] if keys else None
 
 
 def program_config(conf: dict):
     """The program's ``ModelConfig`` built from a configuration file: its
-    registry entry (``arch``) with every size the file states."""
+    registry entry (``arch``) with every key the file states that is not
+    in ``METADATA``, each placed on the field ``_FIELDS`` names or on the
+    field of its own name.  A key with no such field stops the run by
+    name, so a program that learns an architecture adds its fields under
+    the published names.  Two kinds of key are checked instead of placed
+    where the program has no field: ``rms_norm_eps`` against the one
+    epsilon it runs, and the keys of ``_FIXED`` against the one value it
+    runs.
+
+    Experts held: a file that cuts a layer to this chip's share counts the
+    experts held here (listed in ``reduced``) and restates the model's
+    count under ``published``.  The program gets the published count as
+    ``num_experts`` (the router's width) and the file's as
+    ``experts_held``; a program without that field stops the run whenever
+    the two differ.  No other published value reaches the program."""
     import dataclasses
 
     from repro import configs
     base = configs.get_config(conf["arch"])
+    fields = {f.name for f in dataclasses.fields(base)}
+    over = {}
+    for key, value in conf.items():
+        if key in METADATA:
+            continue
+        field = _FIELDS.get(key, key)
+        if field in fields:
+            over[field] = value
+        elif field == "norm_eps":
+            pass                             # checked below
+        elif key not in _FIXED:
+            raise SystemExit(f"program has no field for {key}")
+        elif value != _FIXED[key]:
+            raise SystemExit(f"program runs only {key} = {_FIXED[key]!r}, "
+                             f"the file states {value!r}")
+    ek = expert_key(conf)
+    if ek is not None:
+        held = conf[ek]
+        over["num_experts"] = conf.get("published", {}).get(ek, held)
+        if "experts_held" in fields:
+            over["experts_held"] = held
+        elif held != over["num_experts"]:
+            raise SystemExit(
+                f"program has no field for experts_held: the file holds "
+                f"{held} of {over['num_experts']} experts")
     cfg = dataclasses.replace(
-        base, **{f: conf[k] for k, f in _FIELDS.items()},
-        mpo=dataclasses.replace(base.mpo, **conf["mpo"]),
-        dtype=conf["dtype"], tie_embeddings=conf["tie_word_embeddings"],
-        mlp_act=conf["hidden_act"])
+        base, **over, mpo=dataclasses.replace(base.mpo, **conf["mpo"]),
+        dtype=conf["dtype"])
     if cfg.vocab_size != conf["vocab_size"]:
         raise SystemExit(f"program pads vocab {conf['vocab_size']} to "
                          f"{cfg.vocab_size}; state the padded size")
-    if program_norm_eps(cfg) != conf["rms_norm_eps"]:
+    if "rms_norm_eps" in conf and \
+            program_norm_eps(cfg) != conf["rms_norm_eps"]:
         raise SystemExit(f"program RMSNorm epsilon {program_norm_eps(cfg)} "
                          f"is not the file's {conf['rms_norm_eps']}")
     return cfg
@@ -149,11 +232,34 @@ def program_norm_eps(cfg) -> float:
     return getattr(cfg, "norm_eps", default)
 
 
+def _hashable(value):
+    """Lists as tuples and groups as sorted ``(key, value)`` tuples, so a
+    configuration stays a static argument of a jitted reference."""
+    if isinstance(value, (list, tuple)):
+        return tuple(_hashable(v) for v in value)
+    if isinstance(value, dict):
+        return tuple(sorted((k, _hashable(v)) for k, v in value.items()))
+    return value
+
+
 def reference_config(conf: dict) -> dict:
-    """The sizes the plain reference reads, from the configuration file."""
-    return {k: conf[k] for k in (
-        "num_attention_heads", "num_key_value_heads", "head_dim",
-        "rope_theta", "rms_norm_eps", "qk_norm")}
+    """What the plain reference reads: every key of the configuration file
+    that is not in ``METADATA``, and ``published_<key>`` for each of them
+    that ``published`` restates (a share cut's full count: the router's
+    width, the whole vocabulary)."""
+    out = {k: _hashable(v) for k, v in conf.items() if k not in METADATA}
+    out.update({f"published_{k}": _hashable(v)
+                for k, v in conf.get("published", {}).items() if k in out})
+    return out
+
+
+def id_vocab(conf: dict) -> int:
+    """Token ids are drawn below this: the file's vocabulary (a slice's
+    rows) or the published one where that is smaller (ids past it are
+    the program's padding)."""
+    return min(conf["vocab_size"],
+               conf.get("published", {}).get("vocab_size",
+                                             conf["vocab_size"]))
 
 
 def make_weights(model, seed: int):
@@ -161,7 +267,11 @@ def make_weights(model, seed: int):
     the program's parameter layout (float32 MPO cores, unit norms).  Each
     matrix's cores get ``sigma = (var_W / prod(bonds)) ** (1 / 2n)`` so the
     matrix they contract to has entries of variance ``var_W`` (``1 / fan_in``;
-    the embedding ``0.02 ** 2``)."""
+    the embedding ``0.02 ** 2``).  Every other leaf named ``scale`` is ones;
+    any other leaf of two or more dimensions (a router) is drawn at
+    variance ``1 / fan_in``, ``fan_in`` its second-to-last dimension, and
+    a one-dimensional one (a bias) is zero.  Those draws take a key stream
+    of their own, so the MPO cores do not depend on them."""
     import jax
     import jax.numpy as jnp
     from repro.core import layers as L
@@ -182,8 +292,18 @@ def make_weights(model, seed: int):
         return {nm: sigma * jax.random.normal(k, cores[nm].shape, jnp.float32)
                 for nm, k in zip(names, keys)}
 
+    def one_leaf(key, leaf):
+        if leaf.ndim < 2:
+            return jnp.zeros(leaf.shape, leaf.dtype)
+        sigma = leaf.shape[-2] ** -0.5
+        return (sigma * jax.random.normal(key, leaf.shape, jnp.float32)
+                ).astype(leaf.dtype)
+
     def build(key):
-        counter = [0]
+        counter, others = [0], [0]
+        # the MPO matrices fold in 1, 2, ...; every other leaf folds its
+        # own counter into the key's 0th fold, which no matrix takes
+        other_key = jax.random.fold_in(key, 0)
 
         def walk(node, path):
             if isinstance(node, dict) and "cores" in node:
@@ -193,7 +313,10 @@ def make_weights(model, seed: int):
                                             path == ("embed",))}
             if isinstance(node, dict):
                 return {k: walk(v, path + (k,)) for k, v in node.items()}
-            return jnp.ones(node.shape, node.dtype)     # norm scales
+            if path[-1] == "scale":
+                return jnp.ones(node.shape, node.dtype)     # norm scales
+            others[0] += 1
+            return one_leaf(jax.random.fold_in(other_key, others[0]), node)
         return walk(shapes, ())
 
     lo, hi = seed_words(seed)

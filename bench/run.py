@@ -170,7 +170,7 @@ def _run(info, conf, mix, pins, seed, seconds, trace, control, check_chip,
     params = jax.block_until_ready(harness.make_weights(model, seed))
     log("weights", seconds=now() - t)
     session = Session(cfg, params)
-    vocab = conf.get("published", {}).get("vocab_size", conf["vocab_size"])
+    vocab = harness.id_vocab(conf)
     trace_dir = str(harness.ROOT / ".cache" / "bench" / "trace")
     tracer = Tracer(trace_dir, seconds, mix) if trace else \
         (lambda phase, t: None)

@@ -50,19 +50,25 @@ def _spec_with_left_out(spec):
 
 
 def run(cell, seed=2 ** 31 + 77, seconds=1.0, control=False,
-        readings_out=None):
+        readings_out=None, conf=None, mix=None):
+    """One whole run of ``cell`` on the CPU at tiny sizes: ``conf``
+    overrides the configuration file's keys (default ``TINY_CONF``) and
+    ``mix`` the traffic mix's (default ``TINY_MIX[cell]``), so a cell
+    brings its own tiny sizes (expert counts, ranks) as arguments."""
     from unittest import mock
 
     from bench import harness, run as R
+    conf = TINY_CONF if conf is None else conf
+    mix = TINY_MIX[cell] if mix is None else mix
     spec = _spec_with_left_out(harness.spec())
     with mock.patch.object(harness, "spec", lambda: spec):
         # tiny bonds, with whatever else the configuration's mpo group
         # fixes (a pinned plan) kept
-        mpo = dict(harness.cell(cell)["config"]["mpo"], **TINY_CONF["mpo"])
+        mpo = dict(harness.cell(cell)["config"]["mpo"], **conf["mpo"])
         return R.run_cell(cell, seed, seconds, False, control=control,
                           check_chip=False,
-                          conf_override=dict(TINY_CONF, mpo=mpo),
-                          mix_override=TINY_MIX[cell],
+                          conf_override=dict(conf, mpo=mpo),
+                          mix_override=mix,
                           readings_out=readings_out)
 
 
