@@ -14,8 +14,15 @@ long.  This module provides the serving-side fix:
   out-of-length pages is predicated off with ``pl.when``.
 * ``gather_pages`` — the XLA fallback's view: gathers a slot's pages back
   into a contiguous ``(B, kv_len, KV, Dh)`` tensor so the caller can run
-  the exact same ``nn.attention_scores`` path the dense cache uses (token
-  parity with the dense path is therefore trivial).
+  the same ``nn.attention_scores`` path the dense cache uses.
+* ``decode_windows`` — how the XLA fallback bounds what it gathers: the
+  slots sorted longest first, taken in groups of ``DECODE_GROUP``, and
+  each group given the smallest window of ``window_pages`` that holds its
+  longest context.  ``nn._paged_attention`` gathers each group's window
+  alone; the pool's ``stats()["decode_kv_share"]`` counts the same
+  windows on the host.  Every dropped key lies past its slot's length and
+  is masked anyway, so the result is the same maths as a full-span gather,
+  equal up to f32 reduction order.
 * ``choose_impl`` — the dispatch decision, made at trace time from static
   shape/dtype info.  On measuring substrates it registers both
   implementations with the PR-3 autotuner (``kernels.autotune``) and races
@@ -37,6 +44,7 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -54,13 +62,14 @@ FALLBACKS = 0
 
 def note_fallback(exc: BaseException) -> None:
     """Record (and warn about, once per process per message) a flash ->
-    XLA degradation.  The gather path is bitwise-identical, so serving
-    continues correct-but-slower instead of dying with the kernel."""
+    XLA degradation.  The gather path computes the same softmax (equal up
+    to f32 reduction order), so serving continues instead of dying with
+    the kernel."""
     global FALLBACKS
     FALLBACKS += 1
     warnings.warn(
         f"flash decode-attention failed ({type(exc).__name__}: {exc}); "
-        "falling back to the bitwise-identical XLA gather path",
+        "falling back to the XLA gather path",
         RuntimeWarning, stacklevel=3)
 
 
@@ -224,20 +233,81 @@ def _flash_jit(q, k_pages, v_pages, page_table, lengths, bias,
 
 
 def gather_pages(pages, page_table, layer_idx=None):
-    """(P, ps, KV, Dh) pages + (B, MP) table -> contiguous (B, MP*ps, KV, Dh).
+    """(P, ps, KV, Dh) pages + (B, W) table -> contiguous (B, W*ps, KV, Dh).
+
+    ``page_table`` is the whole table (``W = MP``, the chunk prefill and
+    the full-span decode) or a group's first ``W`` logical pages (a
+    ``decode_windows`` window).
 
     With ``layer_idx``, ``pages`` is the whole layer stack (L, P, ps, KV,
     Dh) and the layer index folds into the same gather: no slice of the
     layer's pool is materialized first.
 
     Unmapped (-1) entries are clamped to page 0 — their positions are past
-    every slot's length, so the caller's mask zeroes them exactly and token
-    parity with the dense-cache path is preserved."""
+    every slot's length, so the caller's mask zeroes their softmax weight
+    exactly."""
     b, mp = page_table.shape
     ps, kv, dh = pages.shape[-3:]
     idx = jnp.maximum(page_table, 0)
     out = pages[idx] if layer_idx is None else pages[layer_idx, idx]
-    return out.reshape(b, mp * ps, kv, dh)         # out: (B, MP, ps, KV, Dh)
+    return out.reshape(b, mp * ps, kv, dh)         # out: (B, W, ps, KV, Dh)
+
+
+DECODE_GROUP = 8        # slots that share one decode window
+
+
+def window_pages(max_pages: int) -> tuple[int, ...]:
+    """The decode windows in pages, smallest first: ``max_pages`` over 8,
+    4, 2 and 1, rounded up, so the largest is the whole span and none is
+    under one page."""
+    return tuple(sorted({-(-max_pages // d) for d in (8, 4, 2, 1)}))
+
+
+def windowed(max_pages: int, model: int = 1) -> bool:
+    """Whether decode gathers per-group windows: only with more than one
+    window, and off a mesh that splits the model (there the full-span
+    gather keeps the pool's pinned layout)."""
+    return model == 1 and len(window_pages(max_pages)) > 1
+
+
+def decode_windows(lengths, page_size: int, max_pages: int):
+    """Sort slots longest first and give each group its window.
+
+    ``lengths``: (B,) keys each slot attends (0 for a parked slot), a
+    ``jnp`` or ``np`` array; the result is of the same kind.  Slots are
+    taken in order in groups of ``DECODE_GROUP`` (or B); the last group
+    holds the remainder.  Returns ``(order, branch, tokens)``:
+
+    * ``order`` (B,): slot indices, longest first (ties keep slot order);
+    * ``branch`` (groups,): 0 for a group with no live slot, else
+      ``1 + i`` for ``window_pages(max_pages)[i]``, the smallest window
+      that holds the group's longest context;
+    * ``tokens`` (groups,): that window in keys, 0 for branch 0."""
+    xp = np if isinstance(lengths, np.ndarray) else jnp
+    group = min(DECODE_GROUP, lengths.shape[0])
+    order = xp.argsort(-lengths, stable=True)
+    longest = lengths[order][::group]               # each group's first
+    spans = xp.asarray([0] + [w * page_size for w in window_pages(max_pages)])
+    # 1 + the number of windows too short for the group's longest slot
+    fits = (spans[1:][None, :] < longest[:, None]).sum(axis=-1) + 1
+    branch = xp.where(longest > 0, fits, 0)
+    return order, branch, spans[branch]
+
+
+def decode_kv_share(lengths, page_size: int, max_pages: int,
+                    model: int = 1) -> float:
+    """Share of the ``(B, max_pages * page_size)`` key span that one decode
+    step's XLA gather reads: each group's size times its window, over the
+    whole span (1.0 where ``windowed`` is false).  ``lengths`` as for
+    ``decode_windows``, on the host."""
+    lengths = np.asarray(lengths)
+    if not windowed(max_pages, model):
+        return 1.0
+    b = lengths.shape[0]
+    group = min(DECODE_GROUP, b)
+    _, _, tokens = decode_windows(lengths, page_size, max_pages)
+    sizes = np.minimum(group, b - group * np.arange(tokens.shape[0]))
+    return float(np.dot(sizes, tokens)) / (b * max_pages * page_size)
 
 
 # --------------------------------------------------------------------------

@@ -255,12 +255,53 @@ def _paged_decode_append(cache, k, v, lead=()):
                 pos=pos + 1)
 
 
+def _windowed_decode(q, kp, vp, table, pos, cfg: AttnCfg, mask, layer_idx):
+    """Single-token decode attention over each slot group's own window
+    (``kernels.decode_attention.decode_windows``): slots sorted longest
+    first, each group of ``DECODE_GROUP`` gathers only the first pages of
+    its window and attends them under the same mask prefix; a group with
+    no live slot returns zeros.  ``pos`` is after the append, so a parked
+    slot (appended at the capacity sentinel) reads past capacity: length
+    0.  Returns (B, 1, KV, G, Dh) in slot order."""
+    from repro.kernels import decode_attention as DA
+    b = q.shape[0]
+    ps, mp = kp.shape[-3], table.shape[1]
+    lengths = jnp.where(pos > mp * ps, 0, pos)
+    order, branch, _ = DA.decode_windows(lengths, ps, mp)
+    mask = jnp.broadcast_to(mask, (b,) + mask.shape[1:])
+    q, mask, table = q[order], mask[order], table[order]
+    group = min(DA.DECODE_GROUP, b)
+
+    def attend(pages, q, mask, table):
+        kc = DA.gather_pages(kp, table[:, :pages], layer_idx)
+        vc = DA.gather_pages(vp, table[:, :pages], layer_idx)
+        w = attention_scores(q, kc, cfg, mask[..., :pages * ps])
+        return jnp.einsum("bkgqs,bskd->bqkgd", w.astype(vc.dtype), vc)
+
+    def empty(q, mask, table):
+        return jnp.zeros((q.shape[0], 1, cfg.num_kv_heads,
+                          cfg.num_heads // cfg.num_kv_heads, cfg.head_dim),
+                         vp.dtype)
+
+    branches = [empty] + [functools.partial(attend, w)
+                          for w in DA.window_pages(mp)]
+    ys = [jax.lax.switch(branch[i], branches, q[lo:lo + group],
+                         mask[lo:lo + group], table[lo:lo + group])
+          for i, lo in enumerate(range(0, b, group))]
+    return jnp.concatenate(ys)[jnp.argsort(order)]
+
+
 def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
                      mask, phase: str, chunk: bool = False, layer_idx=None):
     """Self-attention over a paged KV cache (see ``transformer.init_cache``
     ``paged=True``).  Prefill attends over the in-hand prompt K/V; decode
     appends one row per slot and dispatches to the flash kernel or the
-    XLA gather fallback (``kernels.decode_attention.choose_impl``).
+    XLA gather fallback (``kernels.decode_attention.choose_impl``).  The
+    fallback gathers each group of length-sorted slots over its own page
+    window (``_windowed_decode``); with a single window, or on a mesh that
+    splits the model, it gathers every slot's whole span.  Either way it
+    is the same softmax as the full span, equal up to f32 reduction order:
+    every key it leaves out is masked.
 
     ``chunk=True`` marks a prefill CHUNK starting at the slot's current
     (nonzero) position: the chunk is appended via ``_paged_chunk_append``
@@ -330,11 +371,14 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
                 y = y[:, None]                     # (B, 1, KV, G, Dh)
             except Exception as e:                 # noqa: BLE001
                 # Pallas failures surface at trace/lowering time; degrade
-                # to the bitwise-identical gather path rather than dying.
+                # to the gather path (same maths) rather than dying.
                 # (A compiled-runtime fault is not catchable here — see
                 # docs/resilience.md for the limitation.)
                 DA.note_fallback(e)
                 y = None
+        if y is None and DA.windowed(mp, model):
+            y = _windowed_decode(q, kp, vp, table, new_cache["pos"], cfg,
+                                 mask, layer_idx)
         if y is None:
             kc = DA.gather_pages(kp, table, layer_idx)
             vc = DA.gather_pages(vp, table, layer_idx)

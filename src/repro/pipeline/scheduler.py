@@ -71,8 +71,9 @@ request fails ALONE; healthy tenants keep their slots and their tokens.
   bit-identical to a fault-free run;
 * per-request deadlines (``submit(deadline_s=)``) and a pool wall-clock
   budget (``run(budget_s=)``) expire stragglers as failures;
-* flash decode-attention degrades to the bitwise-identical XLA gather path
-  when the Pallas call raises (``models.nn._paged_attention``).
+* flash decode-attention degrades to the XLA gather path (the same maths,
+  equal up to f32 reduction order) when the Pallas call raises
+  (``models.nn._paged_attention``).
 
 Failures are reported per-request: ``request(rid).status == "failed"`` with
 a stable ``.error`` code (``FailReason`` — the router's retry/trip policy
@@ -316,6 +317,7 @@ class ServePool:
         # ---- stats ----
         self._decode_steps = 0
         self._live_slot_steps = 0       # sum of live slots over decode steps
+        self._kv_share_sum = 0.0        # sum of decode_kv_share over steps
         self._tokens_generated = 0
         self._prefill_tokens = 0        # prompt tokens prefilled (real, unpadded)
         self._decode_tokens = 0         # tokens produced by batched decode
@@ -822,6 +824,8 @@ class ServePool:
             with phase("decode"):
                 tok, logits, self._cache = self._decode(
                     self._sparams, jnp.asarray(self._last_tok), self._cache)
+                if self.paged:              # while the device decodes
+                    self._kv_share_sum += self._decode_kv_share()
             with phase("decode_wait"):
                 # chaos: NaN-poison one slot's logits at the chosen decode
                 # step (host-side copy — device values and healthy slots
@@ -865,6 +869,23 @@ class ServePool:
                 span.set_metadata(finished=finished)
             self._live_slot_steps += advanced
             return advanced
+
+    def _decode_kv_share(self) -> float:
+        """Share of the pool's key span this decode step gathers
+        (``kernels.decode_attention.decode_kv_share``): each live slot
+        attends its context plus the token it appends; every other slot
+        is parked at the capacity sentinel, length 0."""
+        from repro.kernels import decode_attention as DA
+        lengths = np.zeros(self.slots, np.int64)
+        for slot, rid in enumerate(self._slot_rid):
+            if rid is not None:
+                req = self._requests[rid]
+                lengths[slot] = req.prompt.size + len(req.tokens)
+        model = (1 if self.mesh is None else dict(
+            zip(self.mesh.axis_names, self.mesh.devices.shape)).get("model", 1))
+        pages = self.max_len // self.page_size
+        return DA.decode_kv_share(np.minimum(lengths, self.max_len),
+                                  self.page_size, pages, model)
 
     def run(self, budget_s: float | None = None) -> dict[int, np.ndarray]:
         """Drain the pool: step until every submitted request completed (or
@@ -927,7 +948,10 @@ class ServePool:
         longest single entry since the previous ``stats()`` call.
         ``kv_in_place``: the K stack still sits in the device buffer it
         was allocated in (every program that rebinds the cache wrote it in
-        place); ``None`` without a KV cache."""
+        place); ``None`` without a KV cache.  ``decode_kv_share``: the mean
+        over decode steps of the share of the ``(slots, max_len)`` key span
+        the XLA decode gather reads (``_decode_kv_share``); ``None`` for a
+        dense pool or before the first decode step."""
         page_pool = None
         if self.paged:
             pages = int(self._cache["k_pages"].shape[1])
@@ -965,6 +989,9 @@ class ServePool:
             "phases": self._phases.snapshot(),
             "kv_in_place": (None if self._kv_start is None
                             else self._kv_buffers() == self._kv_start),
+            "decode_kv_share": (self._kv_share_sum / self._decode_steps
+                                if self.paged and self._decode_steps
+                                else None),
             # admission retrace accounting: distinct prefill/chunk sequence
             # lengths fed to the batch-1 jit (each is one trace); bucketing
             # bounds this at ~log2(max_len)

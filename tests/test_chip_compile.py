@@ -4,8 +4,10 @@ Interpret mode (the rest of the suite) never applies Mosaic's lowering
 rules, so these tests ask the TPU compiler itself, for a described
 ``v5e:2x2`` topology (no chip attached): the fused ``mpo_linear`` forward
 at the query-projection cores and forward+backward at the K/V-projection
-cores, at every tile height the eligibility gate admits, and the flash
-decode-attention kernel at qwen3-14b's head geometry.
+cores, at every tile height the eligibility gate admits, the flash
+decode-attention kernel at qwen3-14b's head geometry, and the XLA path's
+windowed decode scan at the chat pool's geometry (no copy of the page
+stack into its conditionals).
 
 The topology is described inside a module fixture (never at import: only
 one process may load the TPU library, and collection runs in every test
@@ -15,6 +17,7 @@ without one.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import autotune
 from repro.kernels import decode_attention as DA
 from repro.kernels.mpo_linear import kernel_eligible, mpo_linear
+from repro.models import nn
 
 # qwen3-14b cores (configs/qwen3_14b.py): wq 5120 -> 5120, wk 5120 -> 1024
 WQ = ((1, 5, 5, 25), (25, 8, 8, 128), (128, 8, 8, 128), (128, 4, 4, 16),
@@ -103,3 +107,53 @@ def test_flash_decode_attention_compiles(one_chip):
             _sds((slots,), jnp.int32, one_chip),
             _sds((slots, mp * ps), jnp.float32, one_chip))
     _compile(lambda *a: DA._flash_jit(*a, interpret=False), *args)
+
+
+def test_windowed_decode_scan_keeps_the_page_stack(one_chip):
+    """The chat pool's decode attention (32 slots x 4096 keys in pages of
+    16, 8 KV heads of 128, 4 layers): a layer scan that appends to the
+    donated page stacks and attends through ``nn._windowed_decode``.  The
+    stacks enter the window conditionals without a copy, a slice or a
+    write-back of a stack or of one layer's pool."""
+    layers, slots, mp, ps, kv, g, dh = 4, 32, 256, 16, 8, 5, 128
+    pool = slots * mp
+    cfg = nn.AttnCfg(d_model=kv * g * dh, num_heads=kv * g, num_kv_heads=kv,
+                     head_dim=dh)
+
+    def decode(kp, vp, table, pos, free, count, q, k):
+        kj = jnp.arange(mp * ps)[None, :]
+        mask = (kj <= pos[0][:, None])[:, None, None, :]
+
+        def body(carry, xs):
+            kp, vp, acc = carry
+            table, pos, free, count, i = xs
+            cache = nn._paged_decode_append(
+                dict(k_pages=kp, v_pages=vp, page_table=table, pos=pos,
+                     free_list=free, free_count=count), k, k, (i,))
+            kp, vp = cache["k_pages"], cache["v_pages"]
+            y = nn._windowed_decode(q, kp, vp, cache["page_table"],
+                                    cache["pos"], cfg, mask, i)
+            return (kp, vp, acc + y.astype(jnp.float32).sum()), None
+
+        (kp, vp, acc), _ = jax.lax.scan(
+            body, (kp, vp, jnp.float32(0)),
+            (table, pos, free, count, jnp.arange(layers)))
+        return kp, vp, acc
+
+    stack = (layers, pool, ps, kv, dh)
+    args = (_sds(stack, jnp.bfloat16, one_chip),
+            _sds(stack, jnp.bfloat16, one_chip),
+            _sds((layers, slots, mp), jnp.int32, one_chip),
+            _sds((layers, slots), jnp.int32, one_chip),
+            _sds((layers, pool), jnp.int32, one_chip),
+            _sds((layers,), jnp.int32, one_chip),
+            _sds((slots, 1, kv * g, dh), jnp.bfloat16, one_chip),
+            _sds((slots, 1, kv, dh), jnp.bfloat16, one_chip))
+    hlo = jax.jit(decode, donate_argnums=(0, 1)).lower(
+        *args).compile().as_text()
+    assert hlo.count(" conditional(") == slots // DA.DECODE_GROUP
+    pools = {stack, stack[1:], (1,) + stack[1:]}
+    moves = [(op, dims) for dims, op in re.findall(
+        r"= \w+\[([0-9,]*)\]\S*\s+(dynamic-slice|dynamic-update-slice|copy)\(",
+        hlo) if tuple(int(d) for d in dims.split(",") if d) in pools]
+    assert not moves, moves
