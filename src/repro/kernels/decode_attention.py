@@ -18,9 +18,10 @@ long.  This module provides the serving-side fix:
 * ``decode_windows`` — how the XLA fallback bounds what it gathers: the
   slots sorted longest first, taken in groups of ``DECODE_GROUP``, and
   each group given the smallest window of ``window_pages`` that holds its
-  longest context.  ``nn._paged_attention`` gathers each group's window
-  alone; the pool's ``stats()["decode_kv_share"]`` counts the same
-  windows on the host.  Every dropped key lies past its slot's length and
+  longest context.  ``paged_cache.plan`` chooses them once a decode
+  step, ``nn._windowed_decode`` gathers each group's window alone, and
+  the pool's ``stats()["decode_kv_share"]`` counts the same windows on
+  the host.  Every dropped key lies past its slot's length and
   is masked anyway, so the result is the same maths as a full-span gather,
   equal up to f32 reduction order.
 * ``choose_impl`` — the dispatch decision, made at trace time from static
@@ -30,8 +31,8 @@ long.  This module provides the serving-side fix:
   CPU runs keep the XLA reference path unless ``REPRO_DECODE_ATTN=flash``
   forces the kernel (tests do).
 
-The paged cache itself (page table, free-list allocation, append-on-decode)
-lives in ``models/nn.py`` / ``models/transformer.py``; this module only
+The paged cache itself (page table, page allocation, the once-a-step
+append plan) lives in ``models/paged_cache.py``; this module only
 consumes its leaves.
 """
 

@@ -151,123 +151,18 @@ def causal_mask(sq: int, sk: int, *, window: int | None = None,
     return m[None, None]
 
 
-def _paged_prefill_append(cache, k, v, lead=()):
-    """Write a start-0 prompt's K/V into freshly allocated pages.
-
-    Prefill always begins at position 0 (its masks/positions assume it), so
-    allocation is a vectorized pop of ``ceil(s / ps)`` pages per slot off
-    the free-list stack.  Returns the updated paged-cache leaves.
-
-    ``lead`` indexes the page pools ahead of the page dim: ``(layer,)``
-    when they are the whole layer stack (written in place, see
-    ``apply_attention``), ``()`` for one layer's pool."""
-    b, s = k.shape[0], k.shape[1]
-    kp, vp = cache["k_pages"], cache["v_pages"]
-    table, fl, fc = cache["page_table"], cache["free_list"], cache["free_count"]
-    ps = kp.shape[-3]
-    npg = -(-s // ps)                              # pages per slot (static)
-    pad = npg * ps - s
-    kq = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(kp.dtype)
-    vq = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(vp.dtype)
-    kq = kq.reshape(b, npg, ps, *k.shape[2:])
-    vq = vq.reshape(b, npg, ps, *v.shape[2:])
-    pids = fl[fc - 1 - jnp.arange(b * npg)].reshape(b, npg)
-    at = (*lead, pids.reshape(-1))
-    kp = kp.at[at].set(kq.reshape(b * npg, ps, *k.shape[2:]))
-    vp = vp.at[at].set(vq.reshape(b * npg, ps, *v.shape[2:]))
-    table = table.at[:, :npg].set(pids)
-    return dict(cache, k_pages=kp, v_pages=vp, page_table=table,
-                free_count=fc - b * npg, pos=cache["pos"] + s)
-
-
-def _paged_chunk_append(cache, k, v, lead=()):
-    """Append an ``s``-token prefill CHUNK at each slot's current position,
-    allocating pages lazily for every page boundary the chunk crosses.
-
-    The general form of ``_paged_prefill_append`` (start 0, whole prompt)
-    and ``_paged_decode_append`` (one token): chunked prefill interleaves a
-    long prompt's admission with live decode steps, so chunk ``c`` starts
-    at ``pos = c * chunk_len`` with the first touched page possibly half
-    filled by the previous chunk.  Positions past capacity are redirected
-    out of bounds (dropped), mirroring the decode append."""
-    b, s = k.shape[0], k.shape[1]
-    kp, vp = cache["k_pages"], cache["v_pages"]
-    table, fl, fc = cache["page_table"], cache["free_list"], cache["free_count"]
-    pos = cache["pos"]                             # (B,)
-    p_total, ps = kp.shape[-4], kp.shape[-3]
-    mp = table.shape[1]
-    # map every logical page the chunk touches that has no physical page yet
-    pages = jnp.arange(mp)[None, :]                # (1, MP)
-    lo = pos[:, None] // ps
-    hi = jnp.minimum((pos[:, None] + s - 1) // ps, mp - 1)
-    need = (pages >= lo) & (pages <= hi) & (table < 0)   # (B, MP)
-    flat = need.reshape(-1)
-    rank = jnp.cumsum(flat.astype(jnp.int32)) - 1
-    fresh = fl[fc - 1 - rank].reshape(b, mp)
-    table = jnp.where(need, fresh, table)
-    # scatter the chunk's rows at their global positions
-    g = pos[:, None] + jnp.arange(s)[None, :]      # (B, s) global positions
-    oob = g >= mp * ps
-    lp = jnp.minimum(g // ps, mp - 1)
-    phys = jnp.take_along_axis(table, lp, axis=1)  # (B, s)
-    phys_w = jnp.where(oob, p_total, phys).reshape(-1)
-    off_w = jnp.where(oob, ps, g % ps).reshape(-1)
-    kp = kp.at[(*lead, phys_w, off_w)].set(
-        k.reshape(b * s, *k.shape[2:]).astype(kp.dtype))
-    vp = vp.at[(*lead, phys_w, off_w)].set(
-        v.reshape(b * s, *v.shape[2:]).astype(vp.dtype))
-    return dict(cache, k_pages=kp, v_pages=vp, page_table=table,
-                free_count=fc - jnp.sum(flat.astype(jnp.int32)),
-                pos=pos + s)
-
-
-def _paged_decode_append(cache, k, v, lead=()):
-    """Append one (KV, Dh) row per slot at its own position, allocating a
-    fresh page lazily when a slot crosses a page boundary.
-
-    Slots past capacity (the freed-slot sentinel, or an idle row that ran
-    off the end) neither allocate nor write — their scatter indices are
-    redirected out of bounds, which JAX drops."""
-    b = k.shape[0]
-    kp, vp = cache["k_pages"], cache["v_pages"]
-    table, fl, fc = cache["page_table"], cache["free_list"], cache["free_count"]
-    pos = cache["pos"]                             # (B,)
-    p_total, ps = kp.shape[-4], kp.shape[-3]
-    mp = table.shape[1]
-    oob = pos >= mp * ps
-    lp = jnp.minimum(pos // ps, mp - 1)            # logical page (clamped)
-    off = pos % ps
-    need = (off == 0) & ~oob                       # page-boundary slots
-    # distinct stack entries per allocating slot: pool size is B * MP, so
-    # the stack can never underflow while any slot still has room
-    rank = jnp.cumsum(need.astype(jnp.int32)) - 1
-    fresh = fl[fc - 1 - rank]
-    rows = jnp.arange(b)
-    table = jnp.where(need[:, None],
-                      table.at[rows, lp].set(fresh), table)
-    phys = table[rows, lp]                         # (B,) now mapped
-    phys_w = jnp.where(oob, p_total, phys)         # dropped when oob
-    off_w = jnp.where(oob, ps, off)
-    kp = kp.at[(*lead, phys_w, off_w)].set(k[:, 0].astype(kp.dtype))
-    vp = vp.at[(*lead, phys_w, off_w)].set(v[:, 0].astype(vp.dtype))
-    return dict(cache, k_pages=kp, v_pages=vp, page_table=table,
-                free_count=fc - jnp.sum(need.astype(jnp.int32)),
-                pos=pos + 1)
-
-
-def _windowed_decode(q, kp, vp, table, pos, cfg: AttnCfg, mask, layer_idx):
-    """Single-token decode attention over each slot group's own window
-    (``kernels.decode_attention.decode_windows``): slots sorted longest
-    first, each group of ``DECODE_GROUP`` gathers only the first pages of
-    its window and attends them under the same mask prefix; a group with
-    no live slot returns zeros.  ``pos`` is after the append, so a parked
-    slot (appended at the capacity sentinel) reads past capacity: length
-    0.  Returns (B, 1, KV, G, Dh) in slot order."""
+def _windowed_decode(q, kp, vp, table, order, branch, cfg: AttnCfg, mask,
+                     layer_idx):
+    """Single-token decode attention over each slot group's own window:
+    ``order`` sorts the slots longest first and ``branch`` picks each
+    group's window (``kernels.decode_attention.decode_windows``, chosen
+    once a step by ``paged_cache.plan``).  Each group of ``DECODE_GROUP``
+    gathers only the first pages of its window and attends them under the
+    same mask prefix; a group with no live slot returns zeros.  Returns
+    (B, 1, KV, G, Dh) in slot order."""
     from repro.kernels import decode_attention as DA
     b = q.shape[0]
     ps, mp = kp.shape[-3], table.shape[1]
-    lengths = jnp.where(pos > mp * ps, 0, pos)
-    order, branch, _ = DA.decode_windows(lengths, ps, mp)
     mask = jnp.broadcast_to(mask, (b,) + mask.shape[1:])
     q, mask, table = q[order], mask[order], table[order]
     group = min(DA.DECODE_GROUP, b)
@@ -293,45 +188,45 @@ def _windowed_decode(q, kp, vp, table, pos, cfg: AttnCfg, mask, layer_idx):
 
 def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
                      mask, phase: str, chunk: bool = False, layer_idx=None):
-    """Self-attention over a paged KV cache (see ``transformer.init_cache``
-    ``paged=True``).  Prefill attends over the in-hand prompt K/V; decode
-    appends one row per slot and dispatches to the flash kernel or the
-    XLA gather fallback (``kernels.decode_attention.choose_impl``).  The
-    fallback gathers each group of length-sorted slots over its own page
-    window (``_windowed_decode``); with a single window, or on a mesh that
-    splits the model, it gathers every slot's whole span.  Either way it
-    is the same softmax as the full span, equal up to f32 reduction order:
-    every key it leaves out is masked.
+    """Self-attention over a paged KV cache (``models.paged_cache``).  The
+    program planned this step's append once for the whole stack
+    (``paged_cache.plan``, ``cache["at"]``): this layer writes its K/V at
+    the planned indices and reads through the shared page table.
 
-    ``chunk=True`` marks a prefill CHUNK starting at the slot's current
-    (nonzero) position: the chunk is appended via ``_paged_chunk_append``
-    and its queries attend the whole mapped span (earlier chunks included)
-    through the ``gather_pages`` contiguous view, masked by the caller's
-    offset-aware mask — token-identical to an unchunked prefill.
+    Prefill attends over the in-hand prompt K/V.  ``chunk=True`` marks a
+    prefill CHUNK starting at the slot's current (nonzero) position: its
+    queries attend the whole mapped span (earlier chunks included) through
+    the ``gather_pages`` contiguous view, masked by the caller's
+    offset-aware mask — token-identical to an unchunked prefill.  Decode
+    dispatches to the flash kernel or the XLA gather fallback
+    (``kernels.decode_attention.choose_impl``).  The fallback gathers each
+    group of length-sorted slots over its own page window
+    (``_windowed_decode``); with a single window, or on a mesh that splits
+    the model, it gathers every slot's whole span.  Either way it is the
+    same softmax as the full span, equal up to f32 reduction order: every
+    key it leaves out is masked.
 
     ``layer_idx`` (see ``apply_attention``): the page pools are the whole
     layer stack, written and read at that layer."""
     from repro.kernels import decode_attention as DA
+    from repro.models import paged_cache
     from repro.parallel.ctx import get_mesh, shard_dims
     b, s = q.shape[0], q.shape[1]
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = h // kvh
     lead = () if layer_idx is None else (layer_idx,)
+    at, table = cache["at"], cache["page_table"]
+    kp = paged_cache.write(cache["k_pages"], at, k, layer_idx)
+    vp = paged_cache.write(cache["v_pages"], at, v, layer_idx)
     if s > 1 and chunk:                            # prefill chunk (start >= 0)
-        new_cache = _paged_chunk_append(cache, k, v, lead)
-        kc = DA.gather_pages(new_cache["k_pages"], new_cache["page_table"],
-                             layer_idx)
-        vc = DA.gather_pages(new_cache["v_pages"], new_cache["page_table"],
-                             layer_idx)
+        kc = DA.gather_pages(kp, table, layer_idx)
+        vc = DA.gather_pages(vp, table, layer_idx)
         w = attention_scores(q, kc, cfg, mask)
         y = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(vc.dtype), vc)
     elif s > 1:                                    # prefill (start == 0)
-        new_cache = _paged_prefill_append(cache, k, v, lead)
         w = attention_scores(q, k, cfg, mask[..., :s])
         y = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(v.dtype), v)
     else:                                          # single-token decode
-        new_cache = _paged_decode_append(cache, k, v, lead)
-        kp, vp = new_cache["k_pages"], new_cache["v_pages"]
         # pin the paged layout (``sharding.cache_sharding``) so GSPMD never
         # reshards the pool per layer: KV heads over model where the axis
         # divides them (each device attends its own heads), else the
@@ -342,8 +237,6 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
         pin = ({len(lead) + 2: "model"} if kvh % model == 0
                else {len(lead) + 1: "model"})
         kp, vp = shard_dims(kp, pin), shard_dims(vp, pin)
-        new_cache = dict(new_cache, k_pages=kp, v_pages=vp)
-        table = new_cache["page_table"]
         ps, mp = kp.shape[-3], table.shape[1]
         # the kernel runs per device over its own KV heads (a Mosaic kernel
         # is never partitioned automatically); with heads the model axis
@@ -352,7 +245,7 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
                 if kvh % model == 0 else "xla")
         y = None
         if impl == "flash":
-            lengths = jnp.minimum(new_cache["pos"], mp * ps).astype(jnp.int32)
+            lengths = jnp.minimum(cache["pos"], mp * ps).astype(jnp.int32)
             bias = jnp.where(mask[:, 0, 0], 0.0, DA.MASK_VALUE
                              ).astype(jnp.float32)
             flash = functools.partial(DA.flash_decode_attention,
@@ -377,13 +270,14 @@ def _paged_attention(params, q, k, v, cache, cfg: AttnCfg, mpo: MPOConfig,
                 DA.note_fallback(e)
                 y = None
         if y is None and DA.windowed(mp, model):
-            y = _windowed_decode(q, kp, vp, table, new_cache["pos"], cfg,
-                                 mask, layer_idx)
+            y = _windowed_decode(q, kp, vp, table, at["order"], at["branch"],
+                                 cfg, mask, layer_idx)
         if y is None:
             kc = DA.gather_pages(kp, table, layer_idx)
             vc = DA.gather_pages(vp, table, layer_idx)
             w = attention_scores(q, kc, cfg, mask)
             y = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(vc.dtype), vc)
+    new_cache = dict(cache, k_pages=kp, v_pages=vp)
     y = y.reshape(b, s, h * dh)
     return L.apply_linear(params["wo"], y, cfg=mpo, phase=phase), new_cache
 
@@ -394,24 +288,27 @@ def apply_attention(params, x, cfg: AttnCfg, mpo: MPOConfig, *,
                     layer_idx=None):
     """Returns (y, new_cache).
 
-    ``cache``: dict(k, v, pos) for incremental decode — or the paged form
-    (k_pages / v_pages / page_table / free_list / free_count / pos, see
-    ``transformer.init_cache(paged=True)``), which appends into fixed-size
-    pages and dispatches decode to ``kernels.decode_attention``.  ``kv_x``
-    for cross-attention (ignores cache k/v writes when provided with
-    cache — cross k/v are precomputed in the cache by prefill).  ``phase``
-    feeds the execution engine's per-matrix planning.  ``chunk=True`` marks
-    a multi-token prefill CHUNK continuing at the cache's current position
+    ``cache``: dict(k, v, pos) for incremental decode — or a paged cache
+    planned for this step (``paged_cache.plan``; its write indices under
+    ``"at"``), whose pages take this layer's K/V and whose decode goes to
+    ``kernels.decode_attention``.  ``kv_x`` for cross-attention (ignores
+    cache k/v writes when provided with cache — cross k/v are precomputed
+    in the cache by prefill).  ``phase`` feeds the execution engine's
+    per-matrix planning.  ``chunk=True`` marks a multi-token prefill CHUNK
+    continuing at the cache's current position
     (``transformer.prefill_chunk``): the caller supplies offset-aware
     positions/mask; the dense cache path already appends at ``pos`` for
-    multi-token writes, the paged path switches to the chunked append.
+    multi-token writes, the paged path attends the whole mapped span.
 
     ``layer_idx`` (self-attention caches only): the cache's K/V buffers
     (``k``/``v`` or ``k_pages``/``v_pages``) are the WHOLE layer stack,
     and this layer appends at index ``layer_idx`` in one scatter and reads
     its own slice back — the layer scan carries the stack and updates it in
     place instead of slicing each layer out and stacking it back
-    (``transformer._run_stack``).  The returned cache holds the stacks."""
+    (``transformer._run_stack``).  The returned cache holds the stacks; its
+    positions and page bookkeeping pass through, since the program
+    advances them once for every layer.  Without ``layer_idx`` a dense
+    cache is one layer's own, and advances its own ``pos``."""
     b = x.shape[0]
     h, kvh, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = _split_heads(L.apply_linear(params["wq"], x, cfg=mpo, phase=phase),
@@ -472,7 +369,8 @@ def apply_attention(params, x, cfg: AttnCfg, mpo: MPOConfig, *,
             kc = shard_dims(kc, spec)
             vc = shard_dims(vc, spec)
             k, v = kc[lead], vc[lead]
-            new_cache = {"k": kc, "v": vc, "pos": idx + x.shape[1]}
+            new_cache = (dict(cache, k=kc, v=vc) if lead else
+                         {"k": kc, "v": vc, "pos": idx + x.shape[1]})
         else:  # cross-attention: cache holds precomputed enc k/v
             k, v = cache["k"], cache["v"]
             new_cache = cache
@@ -480,12 +378,6 @@ def apply_attention(params, x, cfg: AttnCfg, mpo: MPOConfig, *,
     y = jnp.einsum("bkgqs,bskd->bqkgd", w.astype(v.dtype), v)
     y = y.reshape(b, y.shape[1], h * dh)
     return L.apply_linear(params["wo"], y, cfg=mpo, phase=phase), new_cache
-
-
-def init_kv_cache(batch: int, max_len: int, cfg: AttnCfg, dtype=jnp.bfloat16):
-    shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "pos": jnp.array(0, jnp.int32)}
 
 
 # --------------------------------------------------------------------------

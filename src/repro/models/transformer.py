@@ -8,14 +8,12 @@ scanned ``is_local`` flag.  VLM configs prepend projected patch embeddings
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import layers as L
-from repro.models import nn
+from repro.models import nn, paged_cache
 from repro.models.moe import apply_moe, init_moe
 
 
@@ -105,42 +103,30 @@ def _layer_fwd(cfg: ModelConfig, x, layer, *, positions, mask, mask_local,
     return ctx.shard_activation(x + f), new_cache, aux
 
 
-# the cache leaves that hold K/V (dense or paged): the large ones
-_KV_LEAVES = ("k", "v", "k_pages", "v_pages")
-
-
 def _run_stack(cfg: ModelConfig, params, x, *, positions, mask, mask_local,
                caches=None, phase="train", chunk=False):
     """Scan the layer stack; returns (x, new_caches, aux_loss_sum).
 
-    With ``caches``, the K/V leaves ride in the scan CARRY as whole layer
-    stacks, with the layer index scanned alongside: each layer appends
-    into its own slice of the stack in place and reads it back, so no
-    layer's K/V is sliced out of the stack and stacked back each step.
-    The small bookkeeping leaves (positions, page tables, free lists) are
-    scanned per layer as before."""
+    With ``caches``, the cache rides in the scan CARRY, with the layer
+    index scanned alongside: each layer appends into its own slice of the
+    K/V stacks in place and reads it back, so no layer's K/V is sliced out
+    of the stack and stacked back each step.  Positions, page bookkeeping
+    and a paged cache's write plan are the same for every layer: they pass
+    through the scan unchanged (``_serve_stack`` advances them once)."""
     flags = _is_local_flags(cfg)
-    kv, rest, idx = {}, None, None
-    if caches is not None:
-        kv = {n: caches[n] for n in _KV_LEAVES if n in caches}
-        rest = {n: a for n, a in caches.items() if n not in kv}
-        idx = jnp.arange(cfg.num_layers)
+    idx = None if caches is None else jnp.arange(cfg.num_layers)
 
     def body(carry, scanned):
-        x, aux_sum, kv = carry
-        layer, flag, cache, i = scanned
+        x, aux_sum, cache = carry
+        layer, flag, i = scanned
         layer = dict(layer)
         if cfg.local_window is not None:
             layer["_is_local"] = flag
-        if cache is not None:
-            cache = dict(cache, **kv)
-        y, new_cache, aux = _layer_fwd(cfg, x, layer, positions=positions,
-                                       mask=mask, mask_local=mask_local,
-                                       cache=cache, phase=phase, chunk=chunk,
-                                       layer_idx=i)
-        if new_cache is not None:
-            kv = {n: new_cache.pop(n) for n in kv}
-        return (y, aux_sum + aux, kv), new_cache
+        y, cache, aux = _layer_fwd(cfg, x, layer, positions=positions,
+                                   mask=mask, mask_local=mask_local,
+                                   cache=cache, phase=phase, chunk=chunk,
+                                   layer_idx=i)
+        return (y, aux_sum + aux, cache), None
 
     if cfg.remat:
         body = jax.checkpoint(
@@ -151,12 +137,10 @@ def _run_stack(cfg: ModelConfig, params, x, *, positions, mask, mask_local,
         layer_params = jax.tree.map(
             lambda a: jnp.broadcast_to(a[0], (cfg.num_layers,) + a.shape[1:]),
             layer_params)
-    (x, aux, kv), new_caches = jax.lax.scan(
-        body, (x, jnp.array(0.0, jnp.float32), kv),
-        (layer_params, flags, rest, idx))
-    if caches is not None:
-        new_caches = dict(new_caches, **kv)
-    return x, new_caches, aux
+    (x, aux, caches), _ = jax.lax.scan(
+        body, (x, jnp.array(0.0, jnp.float32), caches),
+        (layer_params, flags, idx))
+    return x, caches, aux
 
 
 def _logits(cfg: ModelConfig, params, x, phase="train"):
@@ -223,73 +207,64 @@ def forward_cls(params, batch, cfg: ModelConfig):
 
 
 # --------------------------------------------------------------------------
-# serving (prefill / decode with per-layer KV caches)
+# serving (prefill / decode against a KV cache)
 # --------------------------------------------------------------------------
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=None, *,
                paged: bool = False, page_size: int = 16,
                pool_pages: int | None = None):
-    """KV cache with PER-SLOT positions: ``pos`` is (layers, batch), so each
+    """KV cache with PER-SLOT positions: ``pos`` is (batch,), so each
     batch row ("slot") can sit at its own decode offset — the substrate for
     multi-tenant batched decode (``pipeline.scheduler.ServePool``), where
     finished slots are recycled mid-generation without disturbing the
-    positions of live tenants.
+    positions of live tenants.  The K/V stacks keep the leading layers dim
+    the layer scan indexes; positions are held once for every layer.
 
-    ``paged=True`` swaps the dense ``(B, max_len)`` layout for a paged one
-    (vLLM-style): K/V live in a pool of fixed-size pages, each slot maps
-    logical pages to physical ones through its ``page_table`` row, and
-    pages are allocated lazily off a ``free_list`` stack as a slot's
+    ``paged=True`` swaps the dense ``(layers, B, max_len)`` layout for a
+    paged one (vLLM-style, ``models.paged_cache``): K/V live in a pool of
+    fixed-size pages, each slot maps logical pages to physical ones
+    through its page-table row, and pages are allocated lazily as a slot's
     context grows — so decode attention bandwidth scales with a slot's own
     length (``kernels.decode_attention``), and ``ServePool`` returns a
-    finished slot's pages to the pool at recycle.  By default the pool
-    holds ``batch * ceil(max_len / page_size)`` pages (worst case every
-    slot full), so allocation can never exhaust it; pass ``pool_pages``
-    smaller to oversubscribe — then ``ServePool`` enforces page-reservation
-    admission so the free list still never underflows (a raw underflow
-    would wrap ``free_list`` indexing negative and silently alias pages).
-    Every leaf keeps the leading layers dim for the ``lax.scan`` over the
-    stack."""
+    finished slot's pages to the pool at recycle.  ``pool_pages`` below
+    ``batch * max_len / page_size`` oversubscribes the pool."""
     dtype = dtype or cfg.jnp_dtype
     acfg = attn_cfg(cfg)
-    nl = cfg.num_layers
-    if not paged:
-        shape = (nl, batch, max_len, acfg.num_kv_heads, acfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-                "pos": jnp.zeros((nl, batch), jnp.int32)}
-    if page_size <= 0:
-        raise ValueError(f"page_size must be positive, got {page_size}")
-    if max_len % page_size != 0:
-        raise ValueError(
-            f"page_size={page_size} does not divide max_len={max_len}: the "
-            f"tail page would be only partially usable and the page-clamped "
-            f"index maps assume full pages. Use a page_size that divides "
-            f"max_len (e.g. {math.gcd(max_len, page_size)}) or round "
-            f"max_len up to {page_size * (-(-max_len // page_size))}.")
-    mp = max_len // page_size                     # logical pages per slot
-    pool = batch * mp if pool_pages is None else int(pool_pages)
-    if not 1 <= pool <= batch * mp:
-        raise ValueError(
-            f"pool_pages={pool_pages} out of range [1, {batch * mp}] "
-            f"(batch={batch} slots x {mp} pages each); oversubscribe by "
-            f"passing fewer pages than batch*max_pages, never more")
-    pshape = (nl, pool, page_size, acfg.num_kv_heads, acfg.head_dim)
-    return {
-        "k_pages": jnp.zeros(pshape, dtype),
-        "v_pages": jnp.zeros(pshape, dtype),
-        "page_table": jnp.full((nl, batch, mp), -1, jnp.int32),
-        "pos": jnp.zeros((nl, batch), jnp.int32),
-        "free_list": jnp.tile(jnp.arange(pool, dtype=jnp.int32), (nl, 1)),
-        "free_count": jnp.full((nl,), pool, jnp.int32),
-    }
+    if paged:
+        return paged_cache.init(cfg.num_layers, batch, max_len,
+                                acfg.num_kv_heads, acfg.head_dim, dtype,
+                                page_size=page_size, pool_pages=pool_pages)
+    shape = (cfg.num_layers, batch, max_len, acfg.num_kv_heads, acfg.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            "pos": jnp.zeros((batch,), jnp.int32)}
 
 
 def cache_kv_len(cache) -> int:
     """Key span the decode masks cover: ``max_len`` for dense caches, page
-    capacity (``MP * page_size``, >= max_len) for paged ones."""
+    capacity (``paged_cache.span``, >= max_len) for paged ones."""
     if "k_pages" in cache:
-        return cache["page_table"].shape[-1] * cache["k_pages"].shape[2]
+        return paged_cache.span(cache)
     return cache["k"].shape[2]
+
+
+def _serve_stack(cfg: ModelConfig, params, x, cache, *, positions, mask,
+                 mask_local, phase, chunk=False):
+    """Run the layer stack over ``x.shape[1]`` new tokens per slot and
+    advance the cache's bookkeeping once: a paged cache plans its append
+    (``paged_cache.plan``) before the scan and every layer writes at the
+    planned indices; a dense cache writes at ``pos``.  Returns (x, cache)."""
+    s = x.shape[1]
+    if "k_pages" in cache:
+        cache, at = paged_cache.plan(cache, s)
+        x, out, _ = _run_stack(cfg, params, x, positions=positions, mask=mask,
+                               mask_local=mask_local, caches=dict(cache, at=at),
+                               phase=phase, chunk=chunk)
+        return x, {n: out[n] for n in cache}
+    x, out, _ = _run_stack(cfg, params, x, positions=positions, mask=mask,
+                           mask_local=mask_local, caches=cache, phase=phase,
+                           chunk=chunk)
+    return x, dict(out, pos=cache["pos"] + s)
 
 
 def prefill(params, batch, cache, cfg: ModelConfig, *, phase="prefill"):
@@ -300,11 +275,10 @@ def prefill(params, batch, cache, cfg: ModelConfig, *, phase="prefill"):
     positions = jnp.arange(s)[None, :]
     mask = nn.causal_mask(s, max_len)
     mask_local = nn.causal_mask(s, max_len, window=cfg.local_window)
-    x, new_caches, _ = _run_stack(cfg, params, x, positions=positions,
-                                  mask=mask, mask_local=mask_local,
-                                  caches=cache, phase=phase)
+    x, cache = _serve_stack(cfg, params, x, cache, positions=positions,
+                            mask=mask, mask_local=mask_local, phase=phase)
     x = nn.apply_rmsnorm(params["final_norm"], x)
-    return _logits(cfg, params, x[:, -1:], phase), new_caches
+    return _logits(cfg, params, x[:, -1:], phase), cache
 
 
 def prefill_chunk(params, batch, cache, cfg: ModelConfig, *, phase="prefill"):
@@ -327,7 +301,7 @@ def prefill_chunk(params, batch, cache, cfg: ModelConfig, *, phase="prefill"):
     x = _embed_inputs(cfg, params, batch, phase)
     s = x.shape[1]
     max_len = cache_kv_len(cache)
-    start = cache["pos"][0]                        # (B,) per-slot offsets
+    start = cache["pos"]                           # (B,) per-slot offsets
     positions = start[:, None] + jnp.arange(s)[None, :]      # (B, s)
     kj = jnp.arange(max_len)[None, None, :]
     qi = positions[:, :, None]                     # (B, s, 1)
@@ -336,23 +310,23 @@ def prefill_chunk(params, batch, cache, cfg: ModelConfig, *, phase="prefill"):
         mask_local = mask & (kj > qi - cfg.local_window)[:, None]
     else:
         mask_local = mask
-    x, new_caches, _ = _run_stack(cfg, params, x, positions=positions,
-                                  mask=mask, mask_local=mask_local,
-                                  caches=cache, phase=phase, chunk=True)
+    x, cache = _serve_stack(cfg, params, x, cache, positions=positions,
+                            mask=mask, mask_local=mask_local, phase=phase,
+                            chunk=True)
     x = nn.apply_rmsnorm(params["final_norm"], x)
-    return _logits(cfg, params, x, phase), new_caches
+    return _logits(cfg, params, x, phase), cache
 
 
 def decode_step(params, tokens, cache, cfg: ModelConfig, *, phase="decode"):
     """One-token decode against a filled cache.  tokens: (B, 1).
 
-    Positions are per slot (``cache["pos"]``: (layers, batch)): each batch
+    Positions are per slot (``cache["pos"]``: (batch,)): each batch
     row applies RoPE at its own offset and masks keys beyond its own
     position, so rows admitted at different times decode correctly side by
     side in one batched step."""
     x = _embed_inputs(cfg, params, {"tokens": tokens}, phase)
     max_len = cache_kv_len(cache)
-    pos = cache["pos"][0]                          # (B,) per-slot positions
+    pos = cache["pos"]                             # (B,) per-slot positions
     positions = pos[:, None]                       # (B, 1) for rope
     kj = jnp.arange(max_len)[None, :]
     mask = (kj <= pos[:, None])[:, None, None, :]  # (B, 1, 1, S)
@@ -361,8 +335,7 @@ def decode_step(params, tokens, cache, cfg: ModelConfig, *, phase="decode"):
             (kj > pos[:, None] - cfg.local_window)[:, None, None, :]
     else:
         mask_local = mask
-    x, new_caches, _ = _run_stack(cfg, params, x, positions=positions,
-                                  mask=mask, mask_local=mask_local,
-                                  caches=cache, phase=phase)
+    x, cache = _serve_stack(cfg, params, x, cache, positions=positions,
+                            mask=mask, mask_local=mask_local, phase=phase)
     x = nn.apply_rmsnorm(params["final_norm"], x)
-    return _logits(cfg, params, x, phase), new_caches
+    return _logits(cfg, params, x, phase), cache

@@ -161,9 +161,10 @@ def batch_sharding(batch_specs, mesh: Mesh, rules: dict):
 
 
 def cache_sharding(cache_specs, mesh: Mesh, rules: dict):
-    """Decode caches: batch dim is dim 1 (dim 0 = layers) for stacked caches,
-    heads/kv dims sharded over model when divisible.  Integer leaves (the
-    per-slot ``pos`` counters, page tables, free lists) are tiny and stay
+    """Decode caches: batch dim is dim 1 (dim 0 = layers) for the K/V
+    stacks and SSM states, heads/kv dims sharded over model when
+    divisible.  Integer leaves (the per-slot ``pos`` counters and the
+    paged cache's bookkeeping, held once for all layers) are tiny and stay
     replicated — every device needs every slot's position for masking and
     every page mapping for the gather.
 

@@ -107,6 +107,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models import paged_cache
 from repro.pipeline.clock import WallClock
 from repro.pipeline.spans import Phases
 from repro.resilience import faults
@@ -250,7 +251,7 @@ class ServePool:
             # park every slot at the capacity sentinel: idle rows neither
             # write pages nor allocate from the shared pool until a tenant
             # is adopted into them
-            self._cache = jax.jit(self._park_all, donate_argnums=0)(
+            self._cache = jax.jit(paged_cache.park, donate_argnums=0)(
                 self._cache)
         # Admission path: batch-1 prefill over the SAME weight snapshot —
         # serve params are batch-independent, so the pool never contracts
@@ -291,9 +292,9 @@ class ServePool:
             lambda c, n: dict(c, pos=jnp.full_like(c["pos"], n)))
         self.init_seconds = time.perf_counter() - t0
 
-        self._adopt = jax.jit(self._adopt_paged_fn if paged
+        self._adopt = jax.jit(paged_cache.adopt if paged
                               else self._adopt_fn, donate_argnums=0)
-        self._free = (jax.jit(self._free_slot_fn, donate_argnums=0)
+        self._free = (jax.jit(paged_cache.free, donate_argnums=0)
                       if paged else None)
         self._kv_start = self._kv_buffers()
         # per-slot finiteness of the decode logits (device-side reduce: a
@@ -338,84 +339,16 @@ class ServePool:
 
     @staticmethod
     def _adopt_fn(pool_cache, one_cache, slot):
-        """Scatter a batch-1 cache's rows into pool slot ``slot``: every
-        leaf is (layers, batch, ...), so row ``slot`` of each leaf takes the
-        admitted tenant's KV/positions/state while all other rows pass
-        through untouched."""
+        """Scatter a batch-1 dense cache into pool slot ``slot``: row
+        ``slot`` of every K/V stack or SSM state (layers, batch, ...) and
+        of ``pos`` (batch,) takes the admitted tenant's, while all other
+        rows pass through untouched.  (A paged pool adopts through
+        ``paged_cache.adopt``.)"""
         def one(pc, oc):
+            if pc.ndim == 1:                        # per-slot positions
+                return pc.at[slot].set(oc[0])
             return pc.at[:, slot].set(oc[:, 0].astype(pc.dtype))
         return jax.tree.map(one, pool_cache, one_cache)
-
-    # ---- paged-cache slot management ----
-    #
-    # The paged pool (transformer.init_cache(paged=True)) shares one
-    # physical page pool across slots; slot state is the page-table row +
-    # position.  Adoption copies the tenant's batch-1 pages into freshly
-    # popped pool pages; recycling pushes a finished slot's pages back.
-    # Both are per-layer (vmapped over the leading layers dim) because each
-    # layer owns an independent free-list stack.
-
-    @staticmethod
-    def _park_all(cache):
-        """All slots idle: position at the capacity sentinel, so decode
-        writes drop and no pages are allocated for unoccupied rows."""
-        cap = cache["page_table"].shape[-1] * cache["k_pages"].shape[2]
-        return dict(cache, pos=jnp.full_like(cache["pos"], cap))
-
-    @staticmethod
-    def _adopt_paged_fn(pool_cache, one_cache, slot):
-        """Copy a batch-1 tenant cache into pool slot ``slot``: pop one
-        pool page per tenant page in use, copy the page data, and point the
-        slot's table row at the new physical pages."""
-        ps = pool_cache["k_pages"].shape[2]
-        p_total = pool_cache["k_pages"].shape[1]
-        mp = pool_cache["page_table"].shape[-1]
-
-        def layer(kp, vp, tbl, pos, fl, fc, kp1, vp1, tbl1, pos1):
-            n = pos1[0]                             # tenant context length
-            used = jnp.arange(mp) < (n + ps - 1) // ps
-            rank = jnp.cumsum(used.astype(jnp.int32)) - 1
-            pids = fl[fc - 1 - rank]                # popped pool pages
-            pids_w = jnp.where(used, pids, p_total)  # unused -> dropped
-            src = jnp.maximum(tbl1[0], 0)           # tenant physical pages
-            kp = kp.at[pids_w].set(kp1[src].astype(kp.dtype))
-            vp = vp.at[pids_w].set(vp1[src].astype(vp.dtype))
-            tbl = tbl.at[slot].set(jnp.where(used, pids, -1))
-            pos = pos.at[slot].set(n)
-            return (kp, vp, tbl, pos, fl,
-                    fc - jnp.sum(used.astype(jnp.int32)))
-
-        kp, vp, tbl, pos, fl, fc = jax.vmap(layer)(
-            pool_cache["k_pages"], pool_cache["v_pages"],
-            pool_cache["page_table"], pool_cache["pos"],
-            pool_cache["free_list"], pool_cache["free_count"],
-            one_cache["k_pages"], one_cache["v_pages"],
-            one_cache["page_table"], one_cache["pos"])
-        return dict(pool_cache, k_pages=kp, v_pages=vp, page_table=tbl,
-                    pos=pos, free_list=fl, free_count=fc)
-
-    @staticmethod
-    def _free_slot_fn(cache, slot):
-        """Recycle slot ``slot``: push its mapped pages back onto the free
-        list, clear the table row, park the position at the sentinel."""
-        p_total = cache["k_pages"].shape[1]
-        cap = cache["page_table"].shape[-1] * cache["k_pages"].shape[2]
-
-        def layer(tbl, pos, fl, fc):
-            row = tbl[slot]
-            valid = row >= 0
-            rank = jnp.cumsum(valid.astype(jnp.int32)) - 1
-            dest = jnp.where(valid, fc + rank, p_total)  # invalid -> dropped
-            fl = fl.at[dest].set(row)
-            tbl = tbl.at[slot].set(jnp.full_like(row, -1))
-            pos = pos.at[slot].set(cap)
-            return tbl, pos, fl, fc + jnp.sum(valid.astype(jnp.int32))
-
-        tbl, pos, fl, fc = jax.vmap(layer)(
-            cache["page_table"], cache["pos"],
-            cache["free_list"], cache["free_count"])
-        return dict(cache, page_table=tbl, pos=pos, free_list=fl,
-                    free_count=fc)
 
     def _need_pages(self, prompt_len: int, max_new: int) -> int:
         """Worst-case page count a request can ever occupy: the prefill
@@ -955,7 +888,7 @@ class ServePool:
         page_pool = None
         if self.paged:
             pages = int(self._cache["k_pages"].shape[1])
-            used = pages - int(jax.device_get(self._cache["free_count"][0]))
+            used = int(jax.device_get(paged_cache.used_pages(self._cache)))
             page_pool = {"pages": pages, "used": used,
                          "reserved": self._reserved_pages,
                          "page_size": self.page_size,

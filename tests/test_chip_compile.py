@@ -27,7 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import autotune
 from repro.kernels import decode_attention as DA
 from repro.kernels.mpo_linear import kernel_eligible, mpo_linear
-from repro.models import nn
+from repro.models import nn, paged_cache
 
 # qwen3-14b cores (configs/qwen3_14b.py): wq 5120 -> 5120, wk 5120 -> 1024
 WQ = ((1, 5, 5, 25), (25, 8, 8, 128), (128, 8, 8, 128), (128, 4, 4, 16),
@@ -111,10 +111,11 @@ def test_flash_decode_attention_compiles(one_chip):
 
 def test_windowed_decode_scan_keeps_the_page_stack(one_chip):
     """The chat pool's decode attention (32 slots x 4096 keys in pages of
-    16, 8 KV heads of 128, 4 layers): a layer scan that appends to the
-    donated page stacks and attends through ``nn._windowed_decode``.  The
-    stacks enter the window conditionals without a copy, a slice or a
-    write-back of a stack or of one layer's pool."""
+    16, 8 KV heads of 128, 4 layers): one append plan for the step, then a
+    layer scan that writes the donated page stacks at the planned indices
+    and attends through ``nn._windowed_decode``.  The stacks enter the
+    window conditionals without a copy, a slice or a write-back of a stack
+    or of one layer's pool."""
     layers, slots, mp, ps, kv, g, dh = 4, 32, 256, 16, 8, 5, 128
     pool = slots * mp
     cfg = nn.AttnCfg(d_model=kv * g * dh, num_heads=kv * g, num_kv_heads=kv,
@@ -122,31 +123,30 @@ def test_windowed_decode_scan_keeps_the_page_stack(one_chip):
 
     def decode(kp, vp, table, pos, free, count, q, k):
         kj = jnp.arange(mp * ps)[None, :]
-        mask = (kj <= pos[0][:, None])[:, None, None, :]
+        mask = (kj <= pos[:, None])[:, None, None, :]
+        cache, at = paged_cache.plan(
+            dict(k_pages=kp, v_pages=vp, page_table=table, pos=pos,
+                 free_list=free, free_count=count), 1)
 
-        def body(carry, xs):
+        def body(carry, i):
             kp, vp, acc = carry
-            table, pos, free, count, i = xs
-            cache = nn._paged_decode_append(
-                dict(k_pages=kp, v_pages=vp, page_table=table, pos=pos,
-                     free_list=free, free_count=count), k, k, (i,))
-            kp, vp = cache["k_pages"], cache["v_pages"]
+            kp = paged_cache.write(kp, at, k, i)
+            vp = paged_cache.write(vp, at, k, i)
             y = nn._windowed_decode(q, kp, vp, cache["page_table"],
-                                    cache["pos"], cfg, mask, i)
+                                    at["order"], at["branch"], cfg, mask, i)
             return (kp, vp, acc + y.astype(jnp.float32).sum()), None
 
         (kp, vp, acc), _ = jax.lax.scan(
-            body, (kp, vp, jnp.float32(0)),
-            (table, pos, free, count, jnp.arange(layers)))
+            body, (kp, vp, jnp.float32(0)), jnp.arange(layers))
         return kp, vp, acc
 
     stack = (layers, pool, ps, kv, dh)
     args = (_sds(stack, jnp.bfloat16, one_chip),
             _sds(stack, jnp.bfloat16, one_chip),
-            _sds((layers, slots, mp), jnp.int32, one_chip),
-            _sds((layers, slots), jnp.int32, one_chip),
-            _sds((layers, pool), jnp.int32, one_chip),
-            _sds((layers,), jnp.int32, one_chip),
+            _sds((slots, mp), jnp.int32, one_chip),
+            _sds((slots,), jnp.int32, one_chip),
+            _sds((pool,), jnp.int32, one_chip),
+            _sds((), jnp.int32, one_chip),
             _sds((slots, 1, kv * g, dh), jnp.bfloat16, one_chip),
             _sds((slots, 1, kv, dh), jnp.bfloat16, one_chip))
     hlo = jax.jit(decode, donate_argnums=(0, 1)).lower(

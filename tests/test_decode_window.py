@@ -14,8 +14,7 @@ import pytest
 
 from repro import Session
 from repro.kernels import decode_attention as DA
-from repro.models import nn
-from repro.pipeline.scheduler import ServePool
+from repro.models import nn, paged_cache
 from repro.train.steps import make_serve_steps
 
 PS, MP = 4, 16                  # windows of 2, 4, 8, 16 pages: 8..64 keys
@@ -142,8 +141,9 @@ def test_windowed_decode_matches_full_span(case):
     cfg = nn.AttnCfg(d_model=48, num_heads=6, num_kv_heads=2, head_dim=16,
                      attn_softcap=kw.get("softcap"))
     layer = jnp.int32(1)
-    got = jax.jit(nn._windowed_decode, static_argnums=5)(
-        q, kp, vp, table, pos, cfg, mask, layer)
+    order, branch, _ = DA.decode_windows(jnp.where(pos > CAP, 0, pos), PS, MP)
+    got = jax.jit(nn._windowed_decode, static_argnums=6)(
+        q, kp, vp, table, order, branch, cfg, mask, layer)
     want = _full_span(q, kp, vp, table, cfg, mask, layer)
     live = np.asarray(lengths) > 0
     assert got.shape == want.shape
@@ -177,9 +177,9 @@ def _decode_logits(session, prompts, steps):
     each step's logits and the decode program's jaxpr."""
     serve = make_serve_steps(session.model, paged=True, page_size=PS)
     sparams, cache = serve.init_serve(session.params, 16, CAP)
-    cache = jax.jit(ServePool._park_all)(cache)
+    cache = jax.jit(paged_cache.park)(cache)
     template = session.model.init_cache(1, CAP, paged=True, page_size=PS)
-    prefill, adopt = jax.jit(serve.prefill), jax.jit(ServePool._adopt_paged_fn)
+    prefill, adopt = jax.jit(serve.prefill), jax.jit(paged_cache.adopt)
     last = np.zeros((16, 1), np.int32)
     for slot, n in prompts.items():
         logits, one = prefill(sparams, {"tokens": _prompt(n, slot)[None]},
@@ -235,7 +235,7 @@ def test_decode_kv_share_counts_the_device_windows(session):
         jnp.where(pos + 1 > CAP, 0, pos + 1), PS, MP)[2])
 
     def spy(sparams, tok, cache):
-        tokens = np.asarray(choose(cache["pos"][0]))
+        tokens = np.asarray(choose(cache["pos"]))
         device.append(float(8 * tokens.sum()) / (16 * CAP))
         return decode(sparams, tok, cache)
 

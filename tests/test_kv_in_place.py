@@ -3,7 +3,7 @@
 ``transformer._run_stack`` carries the K/V stacks through the scan and
 each layer appends into its own slice; the pool donates its cache to every
 program that rebinds it.  These tests hold the served maths to the
-per-layer form (each layer's cache sliced out of the stack and stacked
+per-layer form (each layer's K/V sliced out of the stacks and stacked
 back), and the compiled decode to having no copy of a page pool."""
 
 import re
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro import Session
-from repro.models import transformer
+from repro.models import paged_cache, transformer
 from repro.pipeline.scheduler import ServePool
 from repro.train.steps import make_serve_steps
 
@@ -30,19 +30,24 @@ def _prompt(n, seed):
     return np.random.default_rng(seed).integers(0, 500, n).astype(np.int32)
 
 
+KV = ("k", "v", "k_pages", "v_pages")
+
+
 def _per_layer_stack(cfg, params, x, *, positions, mask, mask_local,
                      caches=None, phase="train", chunk=False):
-    """The layer stack as a loop: each layer's cache leaves are sliced out
-    of the stack, run through the per-layer attention, and stacked back."""
+    """The layer stack as a loop: each layer's K/V are sliced out of the
+    stacks, run through the per-layer attention, and stacked back; the
+    positions, page table and write plan are shared by every layer."""
     outs = []
     for i in range(cfg.num_layers):
         layer = dict(jax.tree.map(lambda a: a[i], params["layers"]))
-        cache = jax.tree.map(lambda a: a[i], caches)
+        cache = {n: a[i] if n in KV else a for n, a in caches.items()}
         x, new_cache, _ = transformer._layer_fwd(
             cfg, x, layer, positions=positions, mask=mask,
             mask_local=mask_local, cache=cache, phase=phase, chunk=chunk)
         outs.append(new_cache)
-    return x, jax.tree.map(lambda *a: jnp.stack(a), *outs), 0.0
+    stacks = {n: jnp.stack([o[n] for o in outs]) for n in KV if n in caches}
+    return x, dict(outs[-1], **stacks), 0.0
 
 
 def _drive(session, paged):
@@ -55,10 +60,9 @@ def _drive(session, paged):
     sparams, cache = steps.init_serve(session.params, SLOTS, MAX_LEN)
     decode, prefill = jax.jit(steps.decode), jax.jit(steps.prefill)
     chunk = jax.jit(steps.prefill_chunk)
-    adopt = jax.jit(ServePool._adopt_paged_fn if paged
-                    else ServePool._adopt_fn)
+    adopt = jax.jit(paged_cache.adopt if paged else ServePool._adopt_fn)
     if paged:
-        cache = jax.jit(ServePool._park_all)(cache)
+        cache = jax.jit(paged_cache.park)(cache)
     template = session.model.init_cache(1, MAX_LEN, **kw)
     seen, last = [], np.zeros((SLOTS, 1), np.int32)
 
@@ -85,7 +89,7 @@ def _drive(session, paged):
     cache = adopt(cache, one, jnp.int32(2))
     cache = run(cache, 5)
     if paged:
-        cache = jax.jit(ServePool._free_slot_fn)(cache, jnp.int32(0))
+        cache = jax.jit(paged_cache.free)(cache, jnp.int32(0))
     cache = admit_chunked(cache, 0, _prompt(3, 3), 4)
     cache = run(cache, 6)
     return [np.asarray(x) for x in seen], jax.tree.map(np.asarray, cache)
